@@ -46,8 +46,6 @@ WALL_KEYS_PANEL = ("per_scheme_jax_s", "fused_jax_s",
 WALL_KEYS_SERVE = ("engine_wall_s",)
 WALL_KEYS_SERVE_SCAN = ("numpy_sweep_s", "jax_sweep_s",
                         "jax_first_call_s")
-WALL_KEYS_JAX_CACHE = ("cold_first_call_s", "cold_second_shape_s",
-                       "warm_first_call_s", "warm_second_shape_s")
 # episode wall is pinned by LiveConfig.target_wall_s (time-scale solved),
 # so drift here means the coordinator itself got slower; the pure
 # coordination wall is tiny and usually falls under --min-wall (reported,
@@ -103,10 +101,6 @@ def collect_walls(report: dict) -> dict:
         walls[(f"serve_scan.sharded_jax_sweep_s"
                f"@{serve_scan.get('sharded_devices')}dev")] = \
             float(serve_scan["sharded_jax_sweep_s"])
-    jax_cache = report.get("jax_cache", {})
-    for key in WALL_KEYS_JAX_CACHE:
-        if key in jax_cache:
-            walls[f"jax_cache.{key}"] = float(jax_cache[key])
     control = report.get("control_plane", {})
     for key in WALL_KEYS_CONTROL:
         if key in control:

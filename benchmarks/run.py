@@ -20,9 +20,7 @@ dispatch vs the per-scheme loop on the jax / pallas backends), the
 per-policy p99 at a pinned load -- see ``benchmarks.fig_load``), the
 ``serve_scan`` section (the jitted ``lax.scan`` serving backend vs the
 numpy slot loop over the full fig_load sweep, with the Erlang-C anchor
-and the sharded-sweep drift), and the
-``jax_cache`` section (cold vs warm first-call wall with the persistent
-compilation cache), and the ``control_plane`` section (live async
+and the sharded-sweep drift), and the ``control_plane`` section (live async
 execution: measured vs MC-predicted T_comp plus the coordination-wall
 fraction -- see ``repro.control``), and the ``train`` section (the
 batched ``lax.scan`` gradient engine vs the per-unit jitted loop it
@@ -701,74 +699,6 @@ def _bench_serve_scan(reps: int = 2):
     return out
 
 
-def _bench_jax_cache():
-    """Cold vs warm first-call wall with the persistent jax compilation
-    cache (``REPRO_JAX_CACHE_DIR``): two fresh subprocesses share one
-    cache dir, so the second pays a disk read instead of XLA compilation.
-
-    Each subprocess runs TWO different-shaped panels -- (K=12,
-    trials=16) then (K=14, trials=24) -- that K/R shape bucketing pads
-    to the same {rows: 64, K: 16} batch shape.  The second panel's wall
-    inside the COLD process is therefore the bucketing win (one
-    compilation serves both shapes, in-process); the warm process's
-    first wall is the persistent-cache win (the shared bucket entry is
-    read back from disk across processes).
-    """
-    import subprocess
-    import tempfile
-
-    prog = (
-        "import time\n"
-        "import numpy as np\n"
-        "from repro.experiments.engine import "
-        "_maybe_enable_jax_compilation_cache\n"
-        "_maybe_enable_jax_compilation_cache()\n"
-        "from repro.core.schemes import get_scheme\n"
-        "from repro.core.types import HetSpec\n"
-        "sch = get_scheme('work_exchange')\n"
-        "for tag, K, trials in (('A', 12, 16), ('B', 14, 24)):\n"
-        "    het = HetSpec.uniform_random(K, 20.0, 20.0 ** 2 / 6,"
-        " np.random.default_rng(3))\n"
-        "    t0 = time.perf_counter()\n"
-        "    sch.mc_grid([het], 2000, trials=trials,"
-        " rng=np.random.default_rng(0), backend='jax')\n"
-        "    print(f'CALL_{tag} {time.perf_counter() - t0:.4f}')\n"
-    )
-    walls = {}
-    with tempfile.TemporaryDirectory(prefix="repro-jax-cache-") as cache:
-        for phase in ("cold", "warm"):
-            env = dict(os.environ, REPRO_JAX_CACHE_DIR=cache)
-            env.pop("REPRO_SHAPE_BUCKETS", None)   # bucketing must be on
-            try:
-                out = subprocess.run([sys.executable, "-c", prog],
-                                     env=env, capture_output=True,
-                                     text=True, timeout=300)
-            except subprocess.TimeoutExpired:
-                return {"skipped": f"{phase} subprocess timed out"}
-            if out.returncode != 0:
-                return {"skipped": f"{phase} subprocess failed: "
-                                   f"{out.stderr.strip()[-300:]}"}
-            for ln in out.stdout.splitlines():
-                if ln.startswith("CALL_"):
-                    tag, wall = ln.split()
-                    walls[f"{phase}_{tag[5:]}"] = float(wall)
-    cold, warm = walls["cold_A"], walls["warm_A"]
-    return {
-        "cold_first_call_s": round(cold, 4),
-        "cold_second_shape_s": round(walls["cold_B"], 4),
-        "warm_first_call_s": round(warm, 4),
-        "warm_second_shape_s": round(walls["warm_B"], 4),
-        "speedup_warm_vs_cold": round(cold / warm, 2),
-        "speedup_bucket_vs_compile": round(cold / walls["cold_B"], 2),
-        "note": "two different-shaped work_exchange jax panels "
-                "(K=12/trials=16, then K=14/trials=24; both bucket to "
-                "rows=64, K=16) per fresh process, REPRO_JAX_CACHE_DIR "
-                "shared between the two runs: cold_second_shape shows "
-                "in-process bucket reuse, warm_first shows the "
-                "persistent cache serving the shared bucket entry",
-    }
-
-
 def _bench_control_plane(trials: int = 3):
     """The live async control plane at demo scale: ``trials`` executed
     work-exchange episodes (real transport round-trips, jitted matmul
@@ -801,7 +731,6 @@ def _bench_control_plane(trials: int = 3):
                         mc.t_comp_std / np.sqrt(mc_trials)))
     return {
         "K": K, "N": N, "trials": trials, "transport": cfg.transport,
-        "payload_backend": cp["payload_backend"],
         "measured_t_comp": round(cp["measured_t_comp"], 4),
         "mc_predicted_t_comp": round(mc.t_comp, 4),
         "agreement_se": round(abs(rep.t_comp - mc.t_comp) / max(se, 1e-12),
@@ -927,7 +856,7 @@ def run_schemes_json(out_path: Path = Path("results/BENCH_schemes.json")):
               "schemes": {}, "mc_engine": {}, "fig5_grid": {},
               "mds_grid": {}, "fig5_sharded": {}, "fig5_drifting": {},
               "panel": {}, "serve_load": {}, "serve_scan": {},
-              "jax_cache": {}, "control_plane": {}, "train": {}}
+              "control_plane": {}, "train": {}}
 
     # per-trial-loop schemes walk unit ids in Python: bound their budget
     # (the JSON records the actual N/trials used -- no silent caps)
@@ -981,7 +910,6 @@ def run_schemes_json(out_path: Path = Path("results/BENCH_schemes.json")):
     report["panel"] = _bench_panel(n)
     report["serve_load"] = _bench_serve_load()
     report["serve_scan"] = _bench_serve_scan()
-    report["jax_cache"] = _bench_jax_cache()
     report["control_plane"] = _bench_control_plane()
     report["train"] = _bench_train()
 
@@ -1002,10 +930,6 @@ def run_schemes_json(out_path: Path = Path("results/BENCH_schemes.json")):
                  f"drift <= {sc['max_mean_drift_se']} SE"
                  if "speedup" in sc
                  else f"serve scan: {sc.get('skipped', 'n/a')}")
-    jc = report["jax_cache"]
-    cache_note = (f"jax cache warm {jc['speedup_warm_vs_cold']}x vs cold"
-                  if "speedup_warm_vs_cold" in jc
-                  else f"jax cache: {jc.get('skipped', 'n/a')}")
     ctl = report["control_plane"]
     ctl_note = (f"live vs MC {ctl['agreement_se']} SE, coord "
                 f"{100 * ctl['coordination_frac']:.1f}%"
@@ -1026,7 +950,7 @@ def run_schemes_json(out_path: Path = Path("results/BENCH_schemes.json")):
           f"drifting: jax {d['speedup_jax_vs_numpy']}x vs numpy, "
           f"agreement <= {max(d['max_mean_drift_se_jax'], d['max_mean_drift_se_pallas'])} SE; "
           f"fused panel {p['speedup_jax']}x on jax; "
-          f"serve cell {sv['engine_wall_s']}s; {scan_note}; {cache_note}; "
+          f"serve cell {sv['engine_wall_s']}s; {scan_note}; "
           f"{ctl_note}; {train_note})",
           file=sys.stderr)
     checks = []

@@ -3,9 +3,7 @@
 Unit ``u`` is the row block ``A[u*rows:(u+1)*rows]``; a worker assigned
 a queue of units computes the concatenated block's matvec in ONE jitted
 call per round (padded to a power-of-two unit count so a handful of
-traces serve every queue length).  Without jax the same contract runs
-on numpy -- the control plane never hard-depends on an accelerator
-stack.
+traces serve every queue length).
 
 The drawn Exp(1/lambda_k) service clock -- not the matmul wall time --
 governs pacing (the worker sleeps out the remainder), so the executed
@@ -16,19 +14,14 @@ from __future__ import annotations
 
 from typing import Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-try:                                    # optional accelerator path
-    import jax
-    import jax.numpy as jnp
 
-    @jax.jit
-    def _matvec(a, x):
-        return a @ x
-
-    HAVE_JAX = True
-except Exception:                       # pragma: no cover - numpy-only host
-    HAVE_JAX = False
+@jax.jit
+def _matvec(a, x):
+    return a @ x
 
 
 def _bucket(n: int) -> int:
@@ -52,7 +45,6 @@ class MatmulPayload:
         self.y = np.zeros(rows, dtype=np.float32)
         self.done = np.zeros(self.units, dtype=bool)
         self.flops = 0              # multiply-adds issued so far
-        self.backend = "jax" if HAVE_JAX else "numpy"
 
     def _rows_for(self, unit_ids: Sequence[int]) -> np.ndarray:
         ids = np.asarray(unit_ids, dtype=np.int64)
@@ -71,11 +63,7 @@ class MatmulPayload:
             block = np.concatenate(
                 [block, np.zeros((pad_rows - block.shape[0],
                                   self.unit_dim), dtype=np.float32)])
-        if HAVE_JAX:
-            y = np.asarray(_matvec(jnp.asarray(block),
-                                   jnp.asarray(self.x)))
-        else:
-            y = block @ self.x
+        y = np.asarray(_matvec(jnp.asarray(block), jnp.asarray(self.x)))
         self.y[rows] = y[: rows.size]
         self.done[np.asarray(unit_ids, dtype=np.int64)
                   % self.units] = True
@@ -104,4 +92,4 @@ class MatmulPayload:
         return bool(np.allclose(self.y[rows], ref, rtol=1e-4, atol=1e-4))
 
 
-__all__ = ["MatmulPayload", "HAVE_JAX"]
+__all__ = ["MatmulPayload"]

@@ -43,7 +43,7 @@ from repro.core.exchange import Assignment, MasterScheduler
 from repro.core.types import HetSpec
 from repro.core.schemes import MCReport, _report, get_scheme
 
-from .compute import HAVE_JAX, MatmulPayload
+from .compute import MatmulPayload
 from .config import LiveConfig
 from .telemetry import Telemetry
 from .transport import Comm, CommClosedError
@@ -515,7 +515,6 @@ def run_live(scheme_name: str, params: Dict[str, Any], het: HetSpec,
             coord_walls.mean() / max(walls.mean(), 1e-12)),
         "workers_lost": sorted(set(lost)),
         "ledger": ledger,
-        "payload_backend": "jax" if HAVE_JAX else "numpy",
         "timeline": tel.to_dict(),     # last episode, representative
     }
     return _report(scheme.name, ts, its, cs,

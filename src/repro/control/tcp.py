@@ -112,6 +112,7 @@ class TCPListener(Listener):
         self._handle_comm = handle_comm
         self._server: Optional[asyncio.base_events.Server] = None
         self._tasks: list = []
+        self._comms: list = []
 
     async def start(self) -> None:
         _, _, port = _split(self.address)
@@ -123,16 +124,22 @@ class TCPListener(Listener):
     async def _accept(self, reader: asyncio.StreamReader,
                       writer: asyncio.StreamWriter) -> None:
         comm = TCPComm(reader, writer, f"{self.address}#server")
+        self._comms.append(comm)
         self._tasks.append(asyncio.ensure_future(self._handle_comm(comm)))
 
     async def stop(self) -> None:
+        # server-side streams close first: since Python 3.12
+        # ``wait_closed`` waits for every accepted connection to end
+        for t in self._tasks:
+            t.cancel()
+        for comm in self._comms:
+            await comm.close()
+        self._tasks.clear()
+        self._comms.clear()
         if self._server is not None:
             self._server.close()
             await self._server.wait_closed()
             self._server = None
-        for t in self._tasks:
-            t.cancel()
-        self._tasks.clear()
 
 
 def _split(address: str):

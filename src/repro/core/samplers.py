@@ -35,8 +35,8 @@ rounds.  Two backends implement it behind one grid-shaped contract:
     (``repro.kernels.we_rounds``): each program owns a ``(block_b, K)``
     tile of trials and runs the exchange-round loop to completion in
     VMEM.  On hosts without Pallas lowering (CPU CI) it executes a
-    bit-identical jitted ``jnp`` reference (or the kernel under the
-    Pallas interpreter -- ``REPRO_WE_ROUNDS_MODE=interpret``), so the
+    jitted ``jnp`` reference, bit-identical to the kernel under the
+    Pallas interpreter (``REPRO_WE_ROUNDS_MODE=interpret``), so the
     backend is always selectable; the kernel wins on TPU where the jax
     backend is bit-generation-bound.
 
@@ -639,15 +639,13 @@ def _sharded_jax_engine(mesh, drift: bool = False):
 
     Each device runs the whole ``lax.while_loop`` pipeline on its own
     block of batch rows with its own rbg key -- no collectives, so the
-    shards never synchronize until the final gather.  ``check_rep=False``
-    because jax<=0.4 has no replication rule for ``while``.  The drift
+    shards never synchronize until the final gather.  The drift
     variant also shards the ``(B, R, K)`` rate schedule along the batch
     rows, so each device carries only its own rows' schedules.
     """
     if (mesh, drift) in _JAX_SHARDED:
         return _JAX_SHARDED[(mesh, drift)]
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
 
     eng = _get_jax_engine(drift)
@@ -658,16 +656,17 @@ def _sharded_jax_engine(mesh, drift: bool = False):
             def block(keys_b, lam_b, sched_b):
                 return eng(keys_b[0], lam_b, sched_b, n0, threshold, cap,
                            known, max_iter)
-            return shard_map(block, mesh=mesh, in_specs=(spec, spec, spec),
-                             out_specs=spec, check_rep=False)(keys, lam,
-                                                              sched)
+            return jax.shard_map(block, mesh=mesh,
+                                 in_specs=(spec, spec, spec),
+                                 out_specs=spec, check_vma=False)(keys, lam,
+                                                                  sched)
     else:
         def sharded(keys, lam, n0, threshold, cap, known, max_iter):
             def block(keys_b, lam_b):
                 return eng(keys_b[0], lam_b, n0, threshold, cap, known,
                            max_iter)
-            return shard_map(block, mesh=mesh, in_specs=(spec, spec),
-                             out_specs=spec, check_rep=False)(keys, lam)
+            return jax.shard_map(block, mesh=mesh, in_specs=(spec, spec),
+                                 out_specs=spec, check_vma=False)(keys, lam)
 
     fn = jax.jit(sharded, static_argnames=("n0", "threshold", "cap",
                                            "known", "max_iter"))
@@ -785,9 +784,9 @@ def bucket_cols(K: int) -> int:
     the next multiple of 8.  Padded columns carry ``lambda = 0`` and are
     fully masked (never busy, never assigned, estimator prior 0), so two
     panels whose K lands in the same bucket share one compilation -- and
-    one ``REPRO_JAX_CACHE_DIR`` persistent-cache entry -- instead of
-    compiling per shape.  ``REPRO_SHAPE_BUCKETS=0`` disables K/R
-    bucketing (exact shapes, one compile per shape)."""
+    one persistent-cache entry -- instead of compiling per shape.
+    ``REPRO_SHAPE_BUCKETS=0`` disables K/R bucketing (exact shapes, one
+    compile per shape)."""
     if not _shape_buckets_enabled():
         return K
     if K <= 16:
@@ -813,7 +812,7 @@ def grid_bucket_shape(G: int, trials: int, K: int,
     """The padded ``(rows, K[, R])`` bucket a ``(G, trials, K[, R])``
     panel dispatches at -- the compile/persistent-cache key's shape part.
     Two panels with equal buckets (and equal static config) share one
-    compilation and one ``REPRO_JAX_CACHE_DIR`` entry."""
+    compilation and one persistent-cache entry."""
     bucket = 128 if resolve_backend(backend) == "pallas" else 64
     shape = {"rows": _rows_target(G * int(trials), bucket),
              "K": bucket_cols(K)}
@@ -946,7 +945,7 @@ def work_exchange_grid_pallas(lam: np.ndarray, N: int, cfg: ExchangeConfig,
     Same fluid relaxation as the ``jax`` backend but with counter-based
     Threefry bits generated *inside* the kernel, so the whole round
     pipeline -- bit generation included -- is one tiled device pass.  On
-    CPU hosts the bit-identical jnp reference (or the interpreted kernel,
+    CPU hosts the jnp reference (bit-identical to the interpreted kernel,
     ``REPRO_WE_ROUNDS_MODE=interpret``) runs instead; see
     ``repro.kernels.we_rounds.ops``.  The numpy ``rng`` only seeds the
     Threefry key (one draw), keeping call sites generator-driven.
@@ -966,8 +965,8 @@ def work_exchange_grid_pallas(lam: np.ndarray, N: int, cfg: ExchangeConfig,
     # real-K scalars first; the K bucket only adds masked zero columns
     # (note the Threefry counter namespace is keyed by the padded K, so
     # bucketed and unbucketed runs are different -- equally valid --
-    # bit streams; kernel/interpret/reference stay mutually bit-identical
-    # at the padded layout)
+    # bit streams; interpret/reference stay bit-identical at the padded
+    # layout)
     threshold = cfg.threshold_frac * N / K
     cap = (np.inf if cfg.storage_cap_frac is None or known
            else float(np.ceil(cfg.storage_cap_frac * N / K)))
@@ -1473,8 +1472,8 @@ register_backend(SamplerBackend(
     work_exchange_grid=work_exchange_grid_pallas,
     description="fused we_rounds Pallas kernel (counter-based Threefry "
                 "bits + MT gamma + argmin + normal-limit binomial in one "
-                "tiled pass); compiled on TPU, bit-identical jnp "
-                "reference / interpreted kernel on CPU",
+                "tiled pass); compiled on TPU, jnp reference / "
+                "interpreted kernel (bit-identical) on CPU",
     gamma_rows=gamma_rows_pallas,
     coupled_mds_sweep=True,
     work_exchange_panel=work_exchange_panel_pallas),

@@ -41,8 +41,8 @@ from repro.scenarios import (SCENARIO_REGISTRY, ScenarioFamily, get_family,
                              list_families)
 from repro.serving import ServingConfig
 
-from .engine import (JAX_CACHE_ENV, ExperimentResult, execute_plan,
-                     run_experiment)
+from .engine import (ExperimentResult, enable_compilation_cache,
+                     execute_plan, run_experiment)
 from .plan import Plan, SHARDED_BACKENDS, Task, compile_plan
 from .spec import (SPEC_VERSION, ExperimentSpec, ScenarioGrid, SchemeSpec,
                    scheme_spec)
@@ -53,6 +53,7 @@ __all__ = [
     "scheme_spec", "ServingConfig",
     "SCENARIO_REGISTRY", "ScenarioFamily", "get_family", "list_families",
     "Plan", "Task", "SHARDED_BACKENDS", "compile_plan",
-    "ExperimentResult", "execute_plan", "run_experiment", "JAX_CACHE_ENV",
+    "ExperimentResult", "execute_plan", "run_experiment",
+    "enable_compilation_cache",
     "DEFAULT_STORE_ROOT", "ResultsStore", "default_store",
 ]

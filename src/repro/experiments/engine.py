@@ -23,6 +23,7 @@ import dataclasses
 import os
 import platform
 import time
+from pathlib import Path
 from typing import Any, Dict, List, Optional
 
 import numpy as np
@@ -36,25 +37,25 @@ from .store import ResultsStore
 
 RESULT_VERSION = 1
 
-# opt-in persistent jax compilation cache: point this env var at a
-# directory and every jit trace is written through to disk, so the
-# second process (CI rerun, warm benchmark) skips XLA compilation
-JAX_CACHE_ENV = "REPRO_JAX_CACHE_DIR"
+# jax's persistent compilation cache: the directory named by
+# $JAX_COMPILATION_CACHE_DIR when that is set (jax reads it itself),
+# otherwise this fixed, git-ignored directory of the checkout -- the
+# path is part of the cache key, so it must never move between runs
+DEFAULT_JAX_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
-def _maybe_enable_jax_compilation_cache() -> Optional[str]:
-    """Enable jax's persistent compilation cache when ``REPRO_JAX_CACHE_DIR``
-    is set (idempotent; returns the directory, or None when off).  Only
-    touches jax config -- never imports jax when the knob is unset."""
-    cache_dir = os.environ.get(JAX_CACHE_ENV)
-    if not cache_dir:
-        return None
+def enable_compilation_cache() -> str:
+    """Point jax's persistent compilation cache at its one directory and
+    return that directory.  Every path that compiles jax code calls
+    this before its first compile (idempotent; sets nothing when
+    ``JAX_COMPILATION_CACHE_DIR`` is set)."""
     import jax
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    cache_dir = str(DEFAULT_JAX_CACHE_DIR)
     if jax.config.jax_compilation_cache_dir != cache_dir:
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # cache every trace, however small/fast-to-compile
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     return cache_dir
 
 
@@ -96,18 +97,28 @@ class ExperimentResult:
                    wall_s=float(d.get("wall_s", 0.0)))
 
 
-def _environment(plan: Plan) -> Dict[str, Any]:
+def _uses_jax(plan: Plan) -> bool:
+    """Whether executing ``plan`` compiles jax code (the numpy sampler
+    and the numpy serving loop never import jax)."""
+    spec = plan.spec
+    if spec.execution == "live" or spec.training is not None:
+        return True
+    if spec.serving is not None:
+        return spec.serving.backend == "jax"
+    return plan.backend in ("jax", "pallas")
+
+
+def _environment(cache_dir: Optional[str]) -> Dict[str, Any]:
     env: Dict[str, Any] = {
         "numpy": np.__version__,
         "python": platform.python_version(),
     }
-    if plan.backend in ("jax", "pallas"):
+    if cache_dir is not None:
         import jax
         env["jax"] = jax.__version__
         env["jax_devices"] = len(jax.devices())
         env["jax_platform"] = jax.default_backend()
-        if os.environ.get(JAX_CACHE_ENV):
-            env["jax_compilation_cache"] = os.environ[JAX_CACHE_ENV]
+        env["jax_compilation_cache"] = cache_dir
     return env
 
 
@@ -168,22 +179,21 @@ def execute_plan(plan: Plan) -> ExperimentResult:
     """Run a compiled plan (no store interaction)."""
     spec = plan.spec
     t0 = time.perf_counter()
-    if plan.backend in ("jax", "pallas"):
-        _maybe_enable_jax_compilation_cache()
+    cache_dir = enable_compilation_cache() if _uses_jax(plan) else None
     if spec.execution == "live":
         reports = _execute_live(plan)
         return ExperimentResult(spec=spec, spec_hash=plan.spec_hash,
-                                reports=reports, env=_environment(plan),
+                                reports=reports, env=_environment(cache_dir),
                                 wall_s=time.perf_counter() - t0)
     if spec.serving is not None:
         reports = _execute_serving(plan)
         return ExperimentResult(spec=spec, spec_hash=plan.spec_hash,
-                                reports=reports, env=_environment(plan),
+                                reports=reports, env=_environment(cache_dir),
                                 wall_s=time.perf_counter() - t0)
     if spec.training is not None:
         reports = _execute_training(plan)
         return ExperimentResult(spec=spec, spec_hash=plan.spec_hash,
-                                reports=reports, env=_environment(plan),
+                                reports=reports, env=_environment(cache_dir),
                                 wall_s=time.perf_counter() - t0)
     reports: Dict[str, List[MCReport]] = {}
     if spec.panel == "fused":
@@ -206,7 +216,7 @@ def execute_plan(plan: Plan) -> ExperimentResult:
                     for rep in reports[key]:
                         rep.extra["nominal_rates_only"] = 1
         return ExperimentResult(spec=spec, spec_hash=plan.spec_hash,
-                                reports=reports, env=_environment(plan),
+                                reports=reports, env=_environment(cache_dir),
                                 wall_s=time.perf_counter() - t0)
     shard = (grid_sharding(plan.devices) if plan.devices > 1
              else contextlib.nullcontext())
@@ -231,7 +241,7 @@ def execute_plan(plan: Plan) -> ExperimentResult:
                 for rep in reports[task.key]:
                     rep.extra["nominal_rates_only"] = 1
     return ExperimentResult(spec=spec, spec_hash=plan.spec_hash,
-                            reports=reports, env=_environment(plan),
+                            reports=reports, env=_environment(cache_dir),
                             wall_s=time.perf_counter() - t0)
 
 
@@ -256,5 +266,5 @@ def run_experiment(spec: ExperimentSpec,
     return result
 
 
-__all__ = ["RESULT_VERSION", "JAX_CACHE_ENV", "ExperimentResult",
+__all__ = ["RESULT_VERSION", "ExperimentResult", "enable_compilation_cache",
            "execute_plan", "run_experiment"]

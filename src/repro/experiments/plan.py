@@ -82,8 +82,8 @@ class Plan:
         """The padded ``(rows, K[, R])`` shape bucket this plan's panel
         dispatches at on a transform backend (None on the exact numpy
         oracle, which never pads).  Plans with equal buckets share one
-        compilation -- and one ``REPRO_JAX_CACHE_DIR`` persistent-cache
-        entry -- regardless of their raw ``(G, trials, K, R)``."""
+        compilation -- and one persistent-cache entry -- regardless of
+        their raw ``(G, trials, K, R)``."""
         if self.backend not in SHARDED_BACKENDS or not self.het_specs:
             return None
         R = (None if self.rate_schedules is None

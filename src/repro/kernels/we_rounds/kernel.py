@@ -10,9 +10,10 @@ state never round-trips to HBM between rounds, and the only HBM traffic
 is one read of the rate tile and one write of the three per-trial stats.
 
 Because every draw is a pure function of ``(seed, row, worker, round,
-slot)`` (see ``ref.py``, which owns all the math), the kernel is
-bit-identical to ``we_rounds_reference`` for any ``block_b``, and padding
-rows cannot perturb real ones.
+slot)`` (see ``ref.py``, which owns all the math), the kernel in
+interpret mode is bit-identical to ``we_rounds_reference`` for any
+``block_b``, and padding rows cannot perturb real ones; compiled for a
+TPU it matches the reference statistically (see ``ref.py``).
 """
 from __future__ import annotations
 
@@ -86,7 +87,7 @@ def _we_rounds_body(seed_ref, lam_ref, sched_ref, flags_ref, out_ref, *,
     row_ids = base + jax.lax.broadcasted_iota(jnp.int32, (block_b, 1), 0)
 
     def cond(st):
-        return st["active"].any()
+        return (st["active"] > 0).any()
 
     def body(st):
         return ref.round_body(st, lam, inv_lam, row_ids, k0, k1, K=K,
@@ -119,8 +120,8 @@ def we_rounds_pallas(lam_rows: jnp.ndarray, seed: jnp.ndarray,
     drifting-scenario per-round rate schedule as a third input: each
     program carries its tile's ``(block_b, R, K)`` schedule in VMEM and
     reads the current round's rates with one ``pl.ds`` dynamic slice on
-    the trip counter (counters are untouched, so drift runs stay
-    bit-identical to the reference).  ``known_flags`` (optional ``(B, 1)``
+    the trip counter (counters are untouched, so drift runs keep the same
+    bit streams as the reference).  ``known_flags`` (optional ``(B, 1)``
     float32, nonzero = known) is the fused-panel mixed mode: known and
     unknown rows of a whole figure share ONE launch, each row reading its
     own flag (``known`` is then ignored; pass ``known=False``).

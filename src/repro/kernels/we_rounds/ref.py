@@ -9,8 +9,11 @@ engine (``we_rounds_reference``) share one implementation of
   ``(trial, worker, round, slot)``.  Counter-based draws make the pipeline
   embarrassingly parallel AND tiling-invariant: a row's random stream
   depends only on its global row id, never on tile size, loop trip count,
-  or padding rows, so kernel and reference are *bit-identical* and padded
-  rows cannot perturb real ones.
+  or padding rows, so the interpreted kernel and the reference are
+  *bit-identical* and padded rows cannot perturb real ones.  The kernel
+  compiled for a TPU draws the same bits, but Mosaic's float math
+  (transcendentals in particular) rounds differently from XLA's, so
+  there it matches the reference statistically, not bitwise.
 * **Gamma service draws** -- the mean-exact Marsaglia-Tsang transform
   ``d * (1 + z / (3 sqrt(d)))^3`` with the exact boost
   ``Gamma(a) = Gamma(a+1) * U^(1/a)`` chained three times below shape 3
@@ -63,8 +66,10 @@ def threefry2x32(k0, k1, c0, c1) -> Tuple[jnp.ndarray, jnp.ndarray]:
 
 def uniform01(bits: jnp.ndarray) -> jnp.ndarray:
     """uint32 -> float32 uniform in (0, 1): top 24 bits, zero-excluded
-    so ``log(u)`` stays finite."""
-    u = (bits >> _U32(8)).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+    so ``log(u)`` stays finite.  The bits pass through ``int32`` (exact
+    below 2^24) because Mosaic has no ``uint32 -> float32`` cast."""
+    u = ((bits >> _U32(8)).astype(jnp.int32).astype(jnp.float32)
+         * jnp.float32(1.0 / (1 << 24)))
     return jnp.maximum(u, jnp.float32(1e-12))
 
 
@@ -141,7 +146,9 @@ def init_state(rows: int, K: int, n0: float, threshold: float,
         "t_comp": jnp.zeros((rows, 1), jnp.float32),
         "n_comm": jnp.zeros((rows, 1), jnp.float32),
         "iters": jnp.zeros((rows, 1), jnp.int32),
-        "active": jnp.full((rows, 1), n0 > threshold),
+        # int32, not bool: Mosaic cannot carry i1 vectors through the
+        # kernel's while_loop
+        "active": jnp.full((rows, 1), int(n0 > threshold), jnp.int32),
     }
     if with_round:
         # scalar trip counter: every *active* row has proceeded on every
@@ -239,7 +246,7 @@ def round_body(st: Dict[str, jnp.ndarray], lam: jnp.ndarray,
     t_raw = gamma_mt(z_g, u0, u1, u2, jnp.maximum(assign, 0.5), inv_lam)
     t_k = jnp.where(busy, t_raw, jnp.inf)
     t_star = t_k.min(1, keepdims=True)
-    proceed = st["active"] & jnp.isfinite(t_star)
+    proceed = (st["active"] > 0) & jnp.isfinite(t_star)
     fin = t_k == t_star                     # finisher clears its queue
     p = jnp.clip(t_star / t_k, 0.0, 1.0)
     done = binomial_normal(z_b, jnp.maximum(assign - 1.0, 0.0), p)
@@ -258,7 +265,8 @@ def round_body(st: Dict[str, jnp.ndarray], lam: jnp.ndarray,
         "n_comm": upd(st["n_comm"] + jnp.where(started, comm, 0.0),
                       st["n_comm"]),
         "iters": iters,
-        "active": proceed & (n_rem_m > threshold) & (iters < max_iter),
+        "active": (proceed & (n_rem_m > threshold)
+                   & (iters < max_iter)).astype(jnp.int32),
     }
     if "round" in st:
         out["round"] = st["round"] + jnp.int32(1)
@@ -321,12 +329,12 @@ def we_rounds_reference(lam_rows: jnp.ndarray, seed: jnp.ndarray,
                         known: bool, max_iter: int):
     """The whole ``(B, K)`` batch through one ``lax.while_loop``.
 
-    Bit-identical to the Pallas kernel (interpret or compiled) on shared
-    rows for any tiling, because every draw is a pure function of
+    Bit-identical to the Pallas kernel in interpret mode on shared rows
+    for any tiling, because every draw is a pure function of
     ``(seed, row, worker, round, slot)``.  ``sched`` (optional
     ``(B, R, K)``) is the per-round service-rate schedule of the
-    drifting scenarios -- the RNG keying is unchanged, so kernel and
-    reference stay bit-identical with or without drift.
+    drifting scenarios -- the RNG keying is unchanged, so interpreted
+    kernel and reference stay bit-identical with or without drift.
     """
     B, K = lam_rows.shape
     lam = lam_rows.astype(jnp.float32)
@@ -336,7 +344,7 @@ def we_rounds_reference(lam_rows: jnp.ndarray, seed: jnp.ndarray,
     sched_at = None if sched is None else (lambda r: sched_row(sched, r))
 
     def cond(st):
-        return st["active"].any()
+        return (st["active"] > 0).any()
 
     def body(st):
         return round_body(st, lam, inv_lam, row_ids, k0, k1, K=K, cap=cap,
@@ -361,7 +369,7 @@ def we_rounds_reference_panel(lam_rows: jnp.ndarray, seed: jnp.ndarray,
     figure stack into ONE batch (one launch), each row reading its own
     ``known_flags`` entry (float32/bool ``(B,)`` or ``(B, 1)``; nonzero =
     known).  Counters are keyed by the global row id exactly as in the
-    single-scheme path, so the panel keeps the kernel/interpret/reference
+    single-scheme path, so the panel keeps the interpret/reference
     bit-identity -- but it is a *different* (equally valid) bit stream
     than two separate launches, whose rows sit at different ids.
     """
@@ -374,7 +382,7 @@ def we_rounds_reference_panel(lam_rows: jnp.ndarray, seed: jnp.ndarray,
     sched_at = None if sched is None else (lambda r: sched_row(sched, r))
 
     def cond(st):
-        return st["active"].any()
+        return (st["active"] > 0).any()
 
     def body(st):
         return round_body(st, lam, inv_lam, row_ids, k0, k1, K=K, cap=cap,

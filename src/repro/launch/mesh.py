@@ -10,18 +10,11 @@ from __future__ import annotations
 import jax
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """jax >= 0.6 wants explicit AxisType; older jax has neither the enum
-    nor the kwarg.  Auto is the default semantic either way."""
-    if hasattr(jax.sharding, "AxisType"):
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
-    return {}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 1):
@@ -29,7 +22,7 @@ def make_host_mesh(model: int = 1):
     n = len(jax.devices())
     data = n // model
     return jax.make_mesh((data, model), ("data", "model"),
-                         **_axis_type_kwargs(2))
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def dp_axes(mesh) -> tuple:

@@ -15,7 +15,7 @@ Shape discipline is the PR-8 sampler machinery applied to queueing:
 * ``Q`` (``max_queue_jobs``), ``K`` (``bucket_cols``), the slot horizon
   ``S`` and the batch ``B`` are padded to pow2 buckets (opt-out
   ``REPRO_SHAPE_BUCKETS=0``) so every ``ServingConfig`` shape family
-  shares one compilation -- and one ``REPRO_JAX_CACHE_DIR`` entry.  The
+  shares one compilation -- and one persistent-cache entry.  The
   true sizes travel as traced scalars; the numpy engine's dynamic
   ``q_hi`` slicing becomes masking, padded slots are dead (``live``
   flag), padded workers carry rate 0.
@@ -37,7 +37,7 @@ comparison counts.  All replacements are exact (same winners, same
 integer sums), so the engine's numbers are bit-identical to the sorted
 formulation's.
 
-Three further measured wins shape the dispatch (each proven bitwise
+Two further measured wins shape the dispatch (each proven bitwise
 against the plain formulation before landing):
 
 * **host-drawn service budgets.** The per-(slot, row, worker) Poisson
@@ -55,12 +55,6 @@ against the plain formulation before landing):
   re-run exactly the flagged rows at full width -- an exact splice
   (rng-free rows are independent), pinned bitwise by
   ``test_queue_tier_splice_bitwise``.
-* **legacy CPU emitter.** Both jits pass
-  ``compiler_options={"xla_cpu_use_thunk_runtime": False}``: the thunk
-  runtime pays a per-op dispatch fee for every op in the scan body
-  every slot, while the legacy emitter compiles the loop body to
-  straight-line code (~1.8x on this engine; scoped per-jit so other
-  benches keep the default runtime, and a no-op off CPU).
 
 Policies run as scan-compatible pure functions (``_build_policy``),
 derived from the same ``DispatchPolicy`` adapters the numpy loop uses;
@@ -581,22 +575,14 @@ def _compiled_sweep(static: Tuple):
                 st["moved_w"], st["qd_sum"], st["su_w"], st["offered"],
                 st["rejected"], st["over"])
 
-    # the scan body is hundreds of small (B, Q, K) ops: under the thunk
-    # runtime each pays a per-op dispatch (thread-pool handoff) every
-    # slot, which dominates the wall at these shapes.  The legacy
-    # emitter compiles the whole while body to straight-line code --
-    # measured ~1.8x on the fig_load sweep, bit-identical outputs.
-    # Scoped to this jit only; grids with large arrays keep the default.
-    _copts = {"xla_cpu_use_thunk_runtime": False}
     if mesh is None or mesh.size <= 1:
-        return jax.jit(block, compiler_options=_copts)
+        return jax.jit(block)
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     axis = mesh.axis_names[0]
     rows = P(axis)
     rep1 = P(None)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         block, mesh=mesh,
         in_specs=(rows,                 # seeds: one stream per device
                   P(None, axis),        # counts (S, B): rows sharded
@@ -608,8 +594,8 @@ def _compiled_sweep(static: Tuple):
                   rep1),                # scal
         out_specs=(rows, rows, rows, rows, P(axis, None), rows, rows,
                    rows, rows, rows, rows, rows, rows),
-        check_rep=False)
-    return jax.jit(sharded, compiler_options=_copts)
+        check_vma=False)
+    return jax.jit(sharded)
 
 
 # ---------------------------------------------------------------------------

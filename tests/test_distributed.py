@@ -14,16 +14,7 @@ import subprocess
 import sys
 import textwrap
 
-import jax
-import pytest
-
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-# these subprocess bodies are written against the explicit-sharding API
-# (jax.sharding.AxisType / jax.set_mesh), absent from older jax
-pytestmark = pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="requires jax >= 0.6 sharding API (AxisType / set_mesh)")
 
 
 def _run(body: str) -> dict:

@@ -87,9 +87,6 @@ class TestParser:
 
 
 class TestLiveCompile:
-    @pytest.mark.skipif(
-        not hasattr(__import__("jax").sharding, "AxisType"),
-        reason="requires jax >= 0.6 sharding API (AxisType / set_mesh)")
     def test_matches_cost_analysis_on_unrolled(self):
         """Parser dot flops == XLA cost_analysis on a loop-free program."""
         import subprocess
