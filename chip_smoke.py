@@ -22,10 +22,10 @@ SE: per-device key streams differ by design), and the ``fig_load`` scan
 at ``devices=4`` against ``devices=1`` (bitwise: fixed-unit sweeps draw
 no random numbers inside the scan).
 
-Timings printed here are smoke timings (one run each, compilation in the
-first call), not benchmark results.  Everything runs in this one process:
-a chip belongs to one process at a time.  Exits non-zero, printing no
-result line, when no TPU is attached or any check fails.
+It prints no timings: the benchmark (``chipbench``) measures.
+Everything runs in this one process: a chip belongs to one process at a
+time.  Exits non-zero, printing no result line, when no TPU is attached
+or any check fails.
 """
 from __future__ import annotations
 
@@ -33,7 +33,6 @@ import argparse
 import dataclasses
 import json
 import sys
-import time
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parent
@@ -67,14 +66,11 @@ def check(ok: bool, what: str) -> None:
 
 
 def run(spec, label: str):
-    t0 = time.perf_counter()
     res = run_experiment(spec, store=STORE, force=True)
-    wall = time.perf_counter() - t0
     backend = (res.spec.serving.backend if res.spec.serving is not None
                else res.spec.backend)
-    print(f"  {label}: backend={backend} devices={res.spec.devices} "
-          f"wall={wall:.2f}s")
-    return res, wall
+    print(f"  {label}: backend={backend} devices={res.spec.devices}")
+    return res
 
 
 def se_of(a, b) -> float:
@@ -119,11 +115,9 @@ def above_bound(res, label: str) -> None:
 
 def phase_grid(backend: str, panel: str, ref) -> None:
     spec = fig5.experiment(trials=TRIALS, backend=backend, panel=panel)
-    first, t_first = run(spec, f"fig5 {backend} first call")
-    _, t_warm = run(spec, f"fig5 {backend} warm")
-    print(f"    smoke walls: first {t_first:.2f}s, warm {t_warm:.2f}s")
-    agree_grid(first, ref, f"fig5 {backend} vs numpy")
-    above_bound(first, f"fig5 {backend}")
+    res = run(spec, f"fig5 {backend}")
+    agree_grid(res, ref, f"fig5 {backend} vs numpy")
+    above_bound(res, f"fig5 {backend}")
 
 
 def phase_kernel_vs_reference() -> None:
@@ -167,7 +161,7 @@ def serving_runs(backend: str, devices: int = 1, seeds: int = 1):
     serving = dataclasses.replace(base.serving, backend=backend)
     return [run(base.replace(seed=base.seed + i, devices=devices,
                              serving=serving),
-                f"fig_load {backend} x{devices} seed {base.seed + i}")[0]
+                f"fig_load {backend} x{devices} seed {base.seed + i}")
             for i in range(seeds)]
 
 
@@ -218,7 +212,7 @@ def agree_serving(runs, refs, label: str) -> None:
 
 
 def phase_training() -> None:
-    res, _ = run(demo_spec("train"), "train demo")
+    res = run(demo_spec("train"), "train demo")
     curves = [rep.extra["training"]["loss_curve"]
               for rows in res.reports.values() for rep in rows]
     first, last = curves[0][0], curves[0][-1]
@@ -230,7 +224,7 @@ def phase_training() -> None:
 
 
 def phase_live() -> None:
-    res, _ = run(demo_spec("live"), "live demo")
+    res = run(demo_spec("live"), "live demo")
     reps = [rep for rows in res.reports.values() for rep in rows]
     episodes = sum(rep.trials for rep in reps)
     print(f"    episodes completed {episodes}; mean T_comp "
@@ -247,7 +241,7 @@ def one_chip() -> None:
                                       "compiled kernel")
     print("phase: fig5 grid (K=50, N=1e6, 8 points x "
           f"{TRIALS} trials)")
-    ref, _ = run(fig5.experiment(trials=TRIALS, backend="numpy"),
+    ref = run(fig5.experiment(trials=TRIALS, backend="numpy"),
                  "fig5 numpy oracle")
     above_bound(ref, "fig5 numpy")
     phase_grid("pallas", "fused", ref)
@@ -255,10 +249,10 @@ def one_chip() -> None:
     print("phase: kernel vs reference")
     phase_kernel_vs_reference()
     print("phase: AR(1) drift grid")
-    drift_ref, _ = run(fig5.drifting_experiment(trials=TRIALS,
+    drift_ref = run(fig5.drifting_experiment(trials=TRIALS,
                                                 backend="numpy"),
                        "drift numpy oracle")
-    drift, _ = run(fig5.drifting_experiment(trials=TRIALS,
+    drift = run(fig5.drifting_experiment(trials=TRIALS,
                                             backend="pallas"),
                    "drift pallas")
     agree_grid(drift, drift_ref, "drift pallas vs numpy")
@@ -282,8 +276,8 @@ def four_chips() -> None:
         four = fig5.experiment(trials=TRIALS, backend=backend, panel=panel,
                                devices=4)
         check(compile_plan(four).devices == 4, "plan.devices == 4")
-        r1, _ = run(one, f"fig5 {backend} x1")
-        r4, _ = run(four, f"fig5 {backend} x4")
+        r1 = run(one, f"fig5 {backend} x1")
+        r4 = run(four, f"fig5 {backend} x4")
         check(r4.spec.devices == 4, "run on 4 devices")
         agree_grid(r4, r1, f"fig5 {backend} 4 vs 1 devices")
     print("phase: fig_load scan, devices=4 vs 1")
@@ -308,10 +302,8 @@ def main() -> int:
     print(f"device: platform={dev.platform} kind={dev.device_kind} "
           f"count={jax.device_count()} jax={jax.__version__} "
           f"cache={cache}")
-    t0 = time.perf_counter()
     four_chips() if args.four_chips else one_chip()
-    print(f"total wall {time.perf_counter() - t0:.1f}s; "
-          f"{len(FAILED)} failed checks")
+    print(f"{len(FAILED)} failed checks")
     if FAILED:
         return 1
     print(json.dumps({"ok": True, "device": {

@@ -59,6 +59,8 @@ from typing import Callable, Dict, List, Literal, Optional, Tuple
 
 import numpy as np
 
+from repro.tracing import span
+
 from .registry import Registry
 from .assignment import (capped_proportional_assignment_batch,
                          largest_remainder_round_batch)
@@ -971,22 +973,23 @@ def work_exchange_grid_pallas(lam: np.ndarray, N: int, cfg: ExchangeConfig,
     cap = (np.inf if cfg.storage_cap_frac is None or known
            else float(np.ceil(cfg.storage_cap_frac * N / K)))
     G = lam.shape[0]
-    lam_rows = np.repeat(_pad_cols(lam, bucket_cols(K)), int(trials),
-                         axis=0)                         # (B, Kb), grid-major
-    # power-of-two bucket >= 128 (the kernel's tile height): panel-sized
-    # grids share a handful of compilations per process, and the bucket
-    # is always a whole number of tiles
-    lam_rows, B = _pad_rows(lam_rows, bucket=128)
-    sched_rows = None
-    if rate_schedule is not None:
-        sched = np.asarray(rate_schedule, dtype=np.float32)
-        if sched.ndim != 3 or sched.shape[0] != G or sched.shape[2] != K:
-            raise ValueError(f"rate_schedule must be (G={G}, R, K={K}); "
-                             f"got shape {sched.shape}")
-        sched = _pad_sched(sched, bucket_rounds(sched.shape[1]),
-                           bucket_cols(K))
-        sched_rows = _pad_rows_like(np.repeat(sched, int(trials), axis=0),
-                                    lam_rows.shape[0])
+    with span("repro.we_rounds.rows"):
+        lam_rows = np.repeat(_pad_cols(lam, bucket_cols(K)), int(trials),
+                             axis=0)                     # (B, Kb), grid-major
+        # power-of-two bucket >= 128 (the kernel's tile height): panel-sized
+        # grids share a handful of compilations per process, and the bucket
+        # is always a whole number of tiles
+        lam_rows, B = _pad_rows(lam_rows, bucket=128)
+        sched_rows = None
+        if rate_schedule is not None:
+            sched = np.asarray(rate_schedule, dtype=np.float32)
+            if sched.ndim != 3 or sched.shape[0] != G or sched.shape[2] != K:
+                raise ValueError(f"rate_schedule must be (G={G}, R, K={K}); "
+                                 f"got shape {sched.shape}")
+            sched = _pad_sched(sched, bucket_rounds(sched.shape[1]),
+                               bucket_cols(K))
+            sched_rows = _pad_rows_like(np.repeat(sched, int(trials), axis=0),
+                                        lam_rows.shape[0])
     mesh = active_grid_mesh()
     if mesh is not None:
         # sharded executor: one independent seed pair per device (each
@@ -999,7 +1002,7 @@ def work_exchange_grid_pallas(lam: np.ndarray, N: int, cfg: ExchangeConfig,
                                threshold=float(threshold), cap=cap,
                                known=bool(known),
                                max_iter=int(cfg.max_iterations), mesh=mesh,
-                               rate_schedule=sched_rows)
+                               rate_schedule=sched_rows, real_rows=B)
     return t[:B], it[:B], cm[:B]
 
 
@@ -1409,25 +1412,26 @@ def work_exchange_panel_pallas(lam: np.ndarray, N: int,
     threshold = cfg_known.threshold_frac * N / K
     cap_u = (np.inf if cfg_unknown.storage_cap_frac is None
              else float(np.ceil(cfg_unknown.storage_cap_frac * N / K)))
-    half = np.repeat(_pad_cols(lam, bucket_cols(K)), int(trials), axis=0)
-    B = half.shape[0]
-    stacked = np.concatenate([half, half])
-    flags = np.concatenate([np.ones(B, np.float32),
-                            np.zeros(B, np.float32)])
-    stacked, _ = _pad_rows(stacked, bucket=128)
-    flags = np.concatenate(
-        [flags, np.ones(stacked.shape[0] - 2 * B, np.float32)])
-    sched_rows = None
-    if rate_schedule is not None:
-        sched = np.asarray(rate_schedule, dtype=np.float32)
-        if sched.ndim != 3 or sched.shape[0] != G or sched.shape[2] != K:
-            raise ValueError(f"rate_schedule must be (G={G}, R, K={K}); "
-                             f"got shape {sched.shape}")
-        sched = _pad_sched(sched, bucket_rounds(sched.shape[1]),
-                           bucket_cols(K))
-        sched_half = np.repeat(sched, int(trials), axis=0)
-        sched_rows = _pad_rows_like(
-            np.concatenate([sched_half, sched_half]), stacked.shape[0])
+    with span("repro.we_rounds.rows"):
+        half = np.repeat(_pad_cols(lam, bucket_cols(K)), int(trials), axis=0)
+        B = half.shape[0]
+        stacked = np.concatenate([half, half])
+        flags = np.concatenate([np.ones(B, np.float32),
+                                np.zeros(B, np.float32)])
+        stacked, _ = _pad_rows(stacked, bucket=128)
+        flags = np.concatenate(
+            [flags, np.ones(stacked.shape[0] - 2 * B, np.float32)])
+        sched_rows = None
+        if rate_schedule is not None:
+            sched = np.asarray(rate_schedule, dtype=np.float32)
+            if sched.ndim != 3 or sched.shape[0] != G or sched.shape[2] != K:
+                raise ValueError(f"rate_schedule must be (G={G}, R, K={K}); "
+                                 f"got shape {sched.shape}")
+            sched = _pad_sched(sched, bucket_rounds(sched.shape[1]),
+                               bucket_cols(K))
+            sched_half = np.repeat(sched, int(trials), axis=0)
+            sched_rows = _pad_rows_like(
+                np.concatenate([sched_half, sched_half]), stacked.shape[0])
     mesh = active_grid_mesh()
     if mesh is not None:
         # sharded launch: one independent seed pair per device (same
@@ -1440,7 +1444,8 @@ def work_exchange_panel_pallas(lam: np.ndarray, N: int,
                                threshold=float(threshold), cap=cap_u,
                                known=flags,
                                max_iter=int(cfg_known.max_iterations),
-                               mesh=mesh, rate_schedule=sched_rows)
+                               mesh=mesh, rate_schedule=sched_rows,
+                               real_rows=2 * B)
     return {"known": (t[:B], it[:B], cm[:B]),
             "unknown": (t[B:2 * B], it[B:2 * B], cm[B:2 * B])}
 

@@ -41,6 +41,8 @@ from typing import Dict, List, Literal, Optional, Sequence, Tuple, Type
 
 import numpy as np
 
+from repro.tracing import span
+
 from .assignment import (capped_proportional_assignment,
                          largest_remainder_round, proportional_assignment,
                          uniform_assignment)
@@ -426,11 +428,13 @@ def _grid_reports(scheme_name: str, specs: Sequence[HetSpec], trials: int,
                   extra: Optional[Dict[str, float]] = None
                   ) -> List[MCReport]:
     """Slice flat grid-major engine output back into per-spec reports."""
-    ts, its, cs = (np.asarray(a).reshape(len(specs), trials) for a in arrays)
-    base = {"backend": backend_name, **(extra or {})}
-    return [_report(scheme_name, ts[g], its[g], cs[g], keep_trials,
-                    extra=dict(base))
-            for g in range(len(specs))]
+    with span("repro.report"):
+        ts, its, cs = (np.asarray(a).reshape(len(specs), trials)
+                       for a in arrays)
+        base = {"backend": backend_name, **(extra or {})}
+        return [_report(scheme_name, ts[g], its[g], cs[g], keep_trials,
+                        extra=dict(base))
+                for g in range(len(specs))]
 
 
 # ---------------------------------------------------------------------------
@@ -637,9 +641,10 @@ class MDSScheme(Scheme):
         sweep_ts = [ts for _, ts in selection]
         if any(ts is None for ts in sweep_ts) or min(T, self.opt_trials) < T:
             sweep_ts = _mds_order_stat_rows(specs, N, winners, T, draw, rng)
-        return [self._grid_report(specs[g], N, winners[g], sweep_ts[g], T,
-                                  keep_trials, name)
-                for g in range(len(specs))]
+        with span("repro.report"):
+            return [self._grid_report(specs[g], N, winners[g], sweep_ts[g],
+                                      T, keep_trials, name)
+                    for g in range(len(specs))]
 
     def _grid_report(self, het: HetSpec, N: int, L: int, ts: np.ndarray,
                      trials: int, keep_trials: bool, name: str) -> MCReport:
@@ -667,71 +672,73 @@ def _mds_select_L_grid(specs: Sequence[HetSpec], N: int, sweep_trials: int,
     and half the trials (``ceil(sweep_trials / 2)``, floor 16) match the
     independent sweep's selection accuracy at half the draws.
     """
-    K = specs[0].K
-    G = len(specs)
-    draw = get_gamma_rows(name)
-    cand = list(range(1, K + 1))
-    m = np.array([int(np.ceil(N / L)) for L in cand], dtype=np.float64)
-    inv_lam = np.stack([1.0 / h.lambdas for h in specs])
+    with span("repro.mds.select"):
+        K = specs[0].K
+        G = len(specs)
+        draw = get_gamma_rows(name)
+        cand = list(range(1, K + 1))
+        m = np.array([int(np.ceil(N / L)) for L in cand], dtype=np.float64)
+        inv_lam = np.stack([1.0 / h.lambdas for h in specs])
 
-    if get_backend(name).coupled_mds_sweep:
-        ct = max(16, (int(sweep_trials) + 1) // 2)
-        m_asc = m[::-1]                      # ascending m: L = K, K-1, ... 1
-        diffs = np.empty(K)
-        diffs[0] = m_asc[0]
-        diffs[1:] = np.diff(m_asc)
-        # rows spec-major then increment-major, drawn at unit rate (one
-        # compact shape column, a (1, K) scale row -- no G*K*ct-row scale
-        # matrix); the per-worker 1/lambda lands in the same fused pass
-        # that zeroes tied increments (ceil(N/L) ties draw at shape 1)
-        shape_col = np.tile(np.repeat(np.maximum(diffs, 1.0), ct),
-                            G)[:, None]
-        t = draw(shape_col, np.ones((1, K), dtype=np.float32), rng)
-        t = t.reshape(G, K, ct, K)
-        t *= (diffs > 0)[None, :, None, None] * inv_lam[:, None, None, :]
-        cube = np.cumsum(t, axis=1)
-        cube.sort(axis=3)                    # cube[g, i] = T at m_asc[i]
-        out: List[Tuple[int, Optional[np.ndarray]]] = []
+        if get_backend(name).coupled_mds_sweep:
+            ct = max(16, (int(sweep_trials) + 1) // 2)
+            m_asc = m[::-1]                  # ascending m: L = K, K-1, ... 1
+            diffs = np.empty(K)
+            diffs[0] = m_asc[0]
+            diffs[1:] = np.diff(m_asc)
+            # rows spec-major then increment-major, drawn at unit rate (one
+            # compact shape column, a (1, K) scale row -- no G*K*ct-row scale
+            # matrix); the per-worker 1/lambda lands in the same fused pass
+            # that zeroes tied increments (ceil(N/L) ties draw at shape 1)
+            shape_col = np.tile(np.repeat(np.maximum(diffs, 1.0), ct),
+                                G)[:, None]
+            t = draw(shape_col, np.ones((1, K), dtype=np.float32), rng)
+            t = t.reshape(G, K, ct, K)
+            t *= (diffs > 0)[None, :, None, None] * inv_lam[:, None, None, :]
+            cube = np.cumsum(t, axis=1)
+            cube.sort(axis=3)                    # cube[g, i] = T at m_asc[i]
+            out: List[Tuple[int, Optional[np.ndarray]]] = []
+            for g in range(G):
+                best = (1, np.inf)
+                for L in cand:
+                    mean_t = float(cube[g, K - L, :, L - 1].mean())
+                    if mean_t < best[1]:
+                        best = (L, mean_t)
+                out.append((best[0], None))
+            return out
+
+        sweep_trials = int(sweep_trials)
+        shape_col = np.tile(np.repeat(m, sweep_trials), G)[:, None]
+        scale_rows = np.repeat(inv_lam, K * sweep_trials, axis=0)
+        t = draw(shape_col, scale_rows, rng)
+        t.sort(axis=1)
+        t = t.reshape(G, K, sweep_trials, K)
+        out = []
         for g in range(G):
-            best = (1, np.inf)
-            for L in cand:
-                mean_t = float(cube[g, K - L, :, L - 1].mean())
+            best: Tuple[int, float, Optional[np.ndarray]] = (1, np.inf, None)
+            for i, L in enumerate(cand):
+                ts = t[g, i, :, L - 1]
+                mean_t = float(ts.mean())
                 if mean_t < best[1]:
-                    best = (L, mean_t)
-            out.append((best[0], None))
+                    best = (L, mean_t, ts)
+            out.append((best[0], best[2]))
         return out
-
-    sweep_trials = int(sweep_trials)
-    shape_col = np.tile(np.repeat(m, sweep_trials), G)[:, None]
-    scale_rows = np.repeat(inv_lam, K * sweep_trials, axis=0)
-    t = draw(shape_col, scale_rows, rng)
-    t.sort(axis=1)
-    t = t.reshape(G, K, sweep_trials, K)
-    out = []
-    for g in range(G):
-        best: Tuple[int, float, Optional[np.ndarray]] = (1, np.inf, None)
-        for i, L in enumerate(cand):
-            ts = t[g, i, :, L - 1]
-            mean_t = float(ts.mean())
-            if mean_t < best[1]:
-                best = (L, mean_t, ts)
-        out.append((best[0], best[2]))
-    return out
 
 
 def _mds_order_stat_rows(specs: Sequence[HetSpec], N: int,
                          Ls: Sequence[int], trials: int, draw,
                          rng: np.random.Generator) -> List[np.ndarray]:
     """Per-spec T^MDS(L_g) samples, all specs in one gamma_rows call."""
-    K = specs[0].K
-    shape_col = np.repeat(
-        np.array([float(np.ceil(N / L)) for L in Ls]), trials)[:, None]
-    scale_rows = np.repeat(np.stack([1.0 / h.lambdas for h in specs]),
-                           trials, axis=0)
-    t = draw(shape_col, scale_rows, rng)
-    t.sort(axis=1)
-    t = t.reshape(len(specs), trials, K)
-    return [t[g, :, Ls[g] - 1] for g in range(len(specs))]
+    with span("repro.mds.topup"):
+        K = specs[0].K
+        shape_col = np.repeat(
+            np.array([float(np.ceil(N / L)) for L in Ls]), trials)[:, None]
+        scale_rows = np.repeat(np.stack([1.0 / h.lambdas for h in specs]),
+                               trials, axis=0)
+        t = draw(shape_col, scale_rows, rng)
+        t.sort(axis=1)
+        t = t.reshape(len(specs), trials, K)
+        return [t[g, :, Ls[g] - 1] for g in range(len(specs))]
 
 
 def mds_time_samples(het: HetSpec, N: int, L: int, trials: int,
@@ -1002,8 +1009,10 @@ def mc_grid_panel(schemes: Dict[str, Scheme], het_specs: Sequence[HetSpec],
         if rate_schedule is not None:
             kwargs["rate_schedule"] = np.asarray(rate_schedule,
                                                  dtype=np.float64)
-        res = panel_fn(lam, N, schemes[kk].config(), schemes[uk].config(),
-                       int(trials), child[kk], **kwargs)
+        with span("repro.scheme.we_pair"):
+            res = panel_fn(lam, N, schemes[kk].config(),
+                           schemes[uk].config(), int(trials), child[kk],
+                           **kwargs)
         for key, slot in ((kk, "known"), (uk, "unknown")):
             fused[key] = _grid_reports(schemes[key].name, specs,
                                        int(trials), res[slot], keep_trials,
@@ -1016,9 +1025,10 @@ def mc_grid_panel(schemes: Dict[str, Scheme], het_specs: Sequence[HetSpec],
         kwargs = {}
         if rate_schedule is not None and sch.supports_rate_schedule:
             kwargs["rate_schedule"] = rate_schedule
-        out[key] = sch.mc_grid(specs, N, int(trials), child[key],
-                               keep_trials=keep_trials, backend=name,
-                               **kwargs)
+        with span(f"repro.scheme.{sch.name}"):
+            out[key] = sch.mc_grid(specs, N, int(trials), child[key],
+                                   keep_trials=keep_trials, backend=name,
+                                   **kwargs)
     return out
 
 

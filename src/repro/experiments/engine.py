@@ -30,6 +30,7 @@ import numpy as np
 
 from repro.core.samplers import grid_sharding
 from repro.core.schemes import MCReport, get_scheme, mc_grid_panel
+from repro.tracing import span
 
 from .plan import Plan, compile_plan
 from .spec import ExperimentSpec
@@ -230,10 +231,11 @@ def execute_plan(plan: Plan) -> ExperimentResult:
                 # engines follow the schedule; single-shot schemes run
                 # at the nominal (round-0 / window-mean) rates
                 kwargs["rate_schedule"] = plan.rate_schedules
-            reports[task.key] = scheme.mc_grid(
-                plan.het_specs, spec.N, trials=spec.trials,
-                rng=np.random.default_rng(task.seed),
-                backend=plan.backend, **kwargs)
+            with span(f"repro.scheme.{scheme.name}"):
+                reports[task.key] = scheme.mc_grid(
+                    plan.het_specs, spec.N, trials=spec.trials,
+                    rng=np.random.default_rng(task.seed),
+                    backend=plan.backend, **kwargs)
             if plan.rate_schedules is not None and not kwargs:
                 # the grid drifts but this scheme cannot follow it:
                 # stamp the rows so stored results (and the CLI table)
@@ -254,16 +256,18 @@ def run_experiment(spec: ExperimentSpec,
     entry -- what the benchmark harness uses so claim validation always
     reflects fresh numbers while still writing through the store.
     """
-    plan = compile_plan(spec)
-    if store is not None and not force:
-        cached = store.get(plan.spec)
-        if cached is not None:
-            cached.cache_hit = True
-            return cached
-    result = execute_plan(plan)
-    if store is not None:
-        store.put(result)
-    return result
+    with span("repro.study"):
+        with span("repro.plan"):
+            plan = compile_plan(spec)
+        if store is not None and not force:
+            cached = store.get(plan.spec)
+            if cached is not None:
+                cached.cache_hit = True
+                return cached
+        result = execute_plan(plan)
+        if store is not None:
+            store.put(result)
+        return result
 
 
 __all__ = ["RESULT_VERSION", "ExperimentResult", "enable_compilation_cache",

@@ -156,4 +156,5 @@ def we_rounds_pallas(lam_rows: jnp.ndarray, seed: jnp.ndarray,
         out_specs=pl.BlockSpec((block_b, 3), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, 3), jnp.float32),
         interpret=interpret,
+        name="we_rounds",
     )(*args)

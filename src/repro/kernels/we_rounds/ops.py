@@ -27,6 +27,8 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from repro.tracing import count, span
+
 from .kernel import DEFAULT_BLOCK_B, we_rounds_pallas
 from .ref import (gamma_rows_reference, we_rounds_reference,
                   we_rounds_reference_panel)
@@ -50,32 +52,48 @@ def resolve_mode(mode: Optional[str] = None) -> str:
     return name
 
 
+# The jitted entries are named functions: the device trace names each
+# program after its function (``jit_we_rounds``, ``jit_we_rounds_panel``,
+# ``jit_gamma_rows``), so readers of a trace need not match generated names.
+
 @functools.lru_cache(maxsize=None)
 def _jit_reference(n0: float, threshold: float, cap: float, known: bool,
                    max_iter: int):
     import jax
-    return jax.jit(functools.partial(we_rounds_reference, n0=n0,
-                                     threshold=threshold, cap=cap,
-                                     known=known, max_iter=max_iter))
+
+    def we_rounds(lam_rows, seed, sched=None):
+        return we_rounds_reference(lam_rows, seed, sched, n0=n0,
+                                   threshold=threshold, cap=cap,
+                                   known=known, max_iter=max_iter)
+
+    return jax.jit(we_rounds)
 
 
 @functools.lru_cache(maxsize=None)
 def _jit_kernel(n0: float, threshold: float, cap: float, known: bool,
                 max_iter: int, block_b: int, interpret: bool):
     import jax
-    return jax.jit(functools.partial(we_rounds_pallas, n0=n0,
-                                     threshold=threshold, cap=cap,
-                                     known=known, max_iter=max_iter,
-                                     block_b=block_b, interpret=interpret))
+
+    def we_rounds(lam_rows, seed, sched=None):
+        return we_rounds_pallas(lam_rows, seed, sched, n0=n0,
+                                threshold=threshold, cap=cap, known=known,
+                                max_iter=max_iter, block_b=block_b,
+                                interpret=interpret)
+
+    return jax.jit(we_rounds)
 
 
 @functools.lru_cache(maxsize=None)
 def _jit_reference_panel(n0: float, threshold: float, cap: float,
                          max_iter: int):
     import jax
-    return jax.jit(functools.partial(we_rounds_reference_panel, n0=n0,
-                                     threshold=threshold, cap=cap,
-                                     max_iter=max_iter))
+
+    def we_rounds_panel(lam_rows, seed, flags, sched=None):
+        return we_rounds_reference_panel(lam_rows, seed, flags, sched,
+                                         n0=n0, threshold=threshold,
+                                         cap=cap, max_iter=max_iter)
+
+    return jax.jit(we_rounds_panel)
 
 
 @functools.lru_cache(maxsize=None)
@@ -83,13 +101,13 @@ def _jit_kernel_panel(n0: float, threshold: float, cap: float,
                       max_iter: int, block_b: int, interpret: bool):
     import jax
 
-    def fn(lam_rows, seed, flags, sched=None):
+    def we_rounds_panel(lam_rows, seed, flags, sched=None):
         return we_rounds_pallas(lam_rows, seed, sched, flags, n0=n0,
                                 threshold=threshold, cap=cap, known=False,
                                 max_iter=max_iter, block_b=block_b,
                                 interpret=interpret)
 
-    return jax.jit(fn)
+    return jax.jit(we_rounds_panel)
 
 
 @functools.lru_cache(maxsize=None)
@@ -168,7 +186,8 @@ def we_rounds_grid(lam_rows: np.ndarray, seed, *, n0: float,
                    threshold: float, cap: float, known,
                    max_iter: int, mode: Optional[str] = None,
                    block_b: int = DEFAULT_BLOCK_B, mesh=None,
-                   rate_schedule: Optional[np.ndarray] = None
+                   rate_schedule: Optional[np.ndarray] = None,
+                   real_rows: Optional[int] = None
                    ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Fused round pipeline over ``(B, K)`` rate rows -> per-row
     ``(t_comp, iterations, n_comm)`` float64 numpy arrays.
@@ -193,99 +212,113 @@ def we_rounds_grid(lam_rows: np.ndarray, seed, *, n0: float,
     ``lam_rows``) is the drifting-scenario per-round schedule; every mode
     (kernel / interpret / reference) consumes it identically, so drift
     runs keep the interpret/reference bit-identity.
+
+    ``real_rows`` (default ``B``) is how many leading rows are real
+    trials; the rest are the caller's padding.  Each call adds to the
+    ``repro.tracing`` counters (see ``count_row_rounds``).
     """
     import jax.numpy as jnp
 
-    lam_rows = np.asarray(lam_rows, dtype=np.float32)
-    if lam_rows.ndim != 2:
-        raise ValueError(f"lam_rows must be (B, K); got {lam_rows.shape}")
-    B = lam_rows.shape[0]
-    sched = None
-    if rate_schedule is not None:
-        sched = np.asarray(rate_schedule, dtype=np.float32)
-        if sched.ndim != 3 or sched.shape[0] != B:
-            raise ValueError(f"rate_schedule must be (B={B}, R, K); "
-                             f"got {sched.shape}")
-    flags = None
-    if not isinstance(known, (bool, np.bool_)):
-        flags = np.asarray(known, dtype=np.float32).reshape(-1, 1)
-        if flags.shape[0] != B:
-            raise ValueError(f"per-row known flags must have one entry per "
-                             f"row (B={B}); got {flags.shape[0]}")
-        known = False
-    mode = resolve_mode(mode)
-    if mesh is not None and mesh.size > 1:
-        D = int(mesh.size)
-        seed_arr = np.asarray(seed, dtype=np.uint32).reshape(D, 2)
-        # every device block must be a whole number of kernel tiles
-        quantum = D if mode == "reference" else D * block_b
-        pad = (-B) % quantum
-        lam_rows = _pad_rows(lam_rows, pad)
-        sched = _pad_rows(sched, pad)
-        flags = _pad_rows(flags, pad)
-        fn = _jit_sharded(mesh, float(n0), float(threshold), float(cap),
-                          bool(known), int(max_iter), int(block_b), mode,
-                          drift=sched is not None, panel=flags is not None)
-        args = (jnp.asarray(seed_arr), jnp.asarray(lam_rows))
-        if flags is not None:
-            args += (jnp.asarray(flags),)
-        if sched is not None:
-            args += (jnp.asarray(sched),)
-        t, it, cm = fn(*args)
-        return (np.asarray(t, dtype=np.float64)[:B],
-                np.asarray(it, dtype=np.float64)[:B],
-                np.asarray(cm, dtype=np.float64)[:B])
-    seed_arr = np.asarray(seed, dtype=np.uint32).reshape(2)
-
-    pad = (-B) % block_b
-    if pad and mode != "reference":
-        lam_rows = _pad_rows(lam_rows, pad)
-        sched = _pad_rows(sched, pad)
-        flags = _pad_rows(flags, pad)
-
-    if mode == "reference":
-        if flags is not None:
-            fn = _jit_reference_panel(float(n0), float(threshold),
-                                      float(cap), int(max_iter))
-            args = (jnp.asarray(lam_rows), jnp.asarray(seed_arr),
-                    jnp.asarray(flags))
-            t, it, cm = fn(*args) if sched is None else fn(
-                *args, jnp.asarray(sched))
+    with span("repro.we_rounds"):
+        lam_rows = np.asarray(lam_rows, dtype=np.float32)
+        if lam_rows.ndim != 2:
+            raise ValueError(f"lam_rows must be (B, K); got "
+                             f"{lam_rows.shape}")
+        B = lam_rows.shape[0]
+        sched = None
+        if rate_schedule is not None:
+            sched = np.asarray(rate_schedule, dtype=np.float32)
+            if sched.ndim != 3 or sched.shape[0] != B:
+                raise ValueError(f"rate_schedule must be (B={B}, R, K); "
+                                 f"got {sched.shape}")
+        flags = None
+        if not isinstance(known, (bool, np.bool_)):
+            flags = np.asarray(known, dtype=np.float32).reshape(-1, 1)
+            if flags.shape[0] != B:
+                raise ValueError(f"per-row known flags must have one entry "
+                                 f"per row (B={B}); got {flags.shape[0]}")
+            known = False
+        mode = resolve_mode(mode)
+        sharded = mesh is not None and mesh.size > 1
+        if sharded:
+            D = int(mesh.size)
+            seed_arr = np.asarray(seed, dtype=np.uint32).reshape(D, 2)
+            # every device block must be a whole number of kernel tiles
+            quantum = D if mode == "reference" else D * block_b
+            pad = (-B) % quantum
+            fn = _jit_sharded(mesh, float(n0), float(threshold), float(cap),
+                              bool(known), int(max_iter), int(block_b), mode,
+                              drift=sched is not None,
+                              panel=flags is not None)
+            # in reference mode each device runs one loop over its block
+            tile = (B + pad) // D if mode == "reference" else block_b
         else:
-            fn = _jit_reference(float(n0), float(threshold), float(cap),
-                                bool(known), int(max_iter))
-            if sched is None:
-                t, it, cm = fn(jnp.asarray(lam_rows), jnp.asarray(seed_arr))
+            seed_arr = np.asarray(seed, dtype=np.uint32).reshape(2)
+            pad = 0 if mode == "reference" else (-B) % block_b
+            if mode == "reference":
+                fn = (_jit_reference(float(n0), float(threshold), float(cap),
+                                     bool(known), int(max_iter))
+                      if flags is None else
+                      _jit_reference_panel(float(n0), float(threshold),
+                                           float(cap), int(max_iter)))
+                tile = B
             else:
-                t, it, cm = fn(jnp.asarray(lam_rows), jnp.asarray(seed_arr),
-                               jnp.asarray(sched))
-    elif flags is not None:
-        fn = _jit_kernel_panel(float(n0), float(threshold), float(cap),
-                               int(max_iter), int(block_b),
-                               mode == "interpret")
-        sched_arg = None if sched is None else jnp.asarray(sched)
-        out = fn(jnp.asarray(lam_rows), jnp.asarray(seed_arr[None, :]),
-                 jnp.asarray(flags), sched_arg)
-        t, it, cm = out[:, 0], out[:, 1], out[:, 2]
-    else:
-        fn = _jit_kernel(float(n0), float(threshold), float(cap),
-                         bool(known), int(max_iter), int(block_b),
-                         mode == "interpret")
-        if sched is None:
-            out = fn(jnp.asarray(lam_rows), jnp.asarray(seed_arr[None, :]))
-        else:
-            out = fn(jnp.asarray(lam_rows), jnp.asarray(seed_arr[None, :]),
-                     jnp.asarray(sched))
-        t, it, cm = out[:, 0], out[:, 1], out[:, 2]
-    return (np.asarray(t, dtype=np.float64)[:B],
-            np.asarray(it, dtype=np.float64)[:B],
-            np.asarray(cm, dtype=np.float64)[:B])
+                interpret = mode == "interpret"
+                fn = (_jit_kernel(float(n0), float(threshold), float(cap),
+                                  bool(known), int(max_iter), int(block_b),
+                                  interpret)
+                      if flags is None else
+                      _jit_kernel_panel(float(n0), float(threshold),
+                                        float(cap), int(max_iter),
+                                        int(block_b), interpret))
+                seed_arr = seed_arr[None, :]
+                tile = block_b
+        rows = tuple(_pad_rows(a, pad) for a in (lam_rows, flags, sched))
+        # the sharded entry takes the seeds first, the others second
+        host = ((seed_arr,) + rows if sharded
+                else (rows[0], seed_arr) + rows[1:])
+        with span("repro.we_rounds.h2d"):
+            args = tuple(jnp.asarray(a) for a in host if a is not None)
+        with span("repro.we_rounds.launch"):
+            out = fn(*args)
+            if not isinstance(out, tuple):     # the kernel's (B, 3) block
+                out = out[:, 0], out[:, 1], out[:, 2]
+        with span("repro.we_rounds.wait"):
+            t, it, cm = (np.asarray(a, dtype=np.float64) for a in out)
+        count_row_rounds(it, B if real_rows is None else int(real_rows),
+                         tile)
+    return t[:B], it[:B], cm[:B]
+
+
+def count_row_rounds(it: np.ndarray, real_rows: int, tile: int) -> None:
+    """Add one launch's row-rounds to the ``repro.tracing`` counters.
+
+    ``it`` is the launch's whole per-row ``iterations`` output, padding
+    included, in tile order; a tile of ``tile`` rows loops until its
+    slowest row is done.  ``we_rounds.row_rounds_useful`` gains the sum
+    of ``it`` over the first ``real_rows`` rows, and
+    ``we_rounds.row_rounds_executed`` gains ``tile`` times the sum over
+    tiles of the tile's largest ``it``: padding rows are executed, never
+    useful.  ``it`` counts the final phase's one round (``ref.py``'s
+    ``final_phase``) on both sides, so a tile whose slowest row had
+    rounds left at the threshold executes its loop's trips plus one.
+    ``tile`` is ``block_b`` for the Pallas kernel and the whole batch
+    (one ``while_loop``) for the reference.
+    """
+    count("we_rounds.row_rounds_useful", int(it[:real_rows].sum()))
+    count("we_rounds.row_rounds_executed",
+          tile * int(it.reshape(-1, tile).max(axis=1).sum()))
 
 
 @functools.lru_cache(maxsize=4)
 def _jit_gamma_rows(boost: bool):
     import jax
-    return jax.jit(functools.partial(gamma_rows_reference, boost=boost))
+
+    def gamma_rows(shape_rows, scale_rows, seed):
+        return gamma_rows_reference(shape_rows, scale_rows, seed,
+                                    boost=boost)
+
+    return jax.jit(gamma_rows)
 
 
 def gamma_rows_grid(shape_rows: np.ndarray, scale_rows: np.ndarray,
