@@ -48,7 +48,10 @@ grid-major order; ``repro.core.schemes`` reshapes them into per-spec
 ``MCReport`` rows.  Backends also expose ``gamma_rows`` -- batched
 ``Gamma(shape) * scale`` over an ``(R, K)`` matrix in one call -- which
 is what the batched MDS L-sweep draws through (``numpy`` is bit-identical
-to the per-L loop; ``jax``/``pallas`` use their transform samplers).
+to the per-L loop; ``jax``/``pallas`` use their transform samplers), and
+may expose ``one_shot_times`` -- a one-shot scheme's draws and per-trial
+completion times in one device dispatch (``jax``/``pallas``; ``numpy``
+leaves it unset and the schemes keep their exact host draw).
 """
 from __future__ import annotations
 
@@ -84,6 +87,10 @@ WEGridFn = Callable[..., GridArrays]
 # (shape_rows, scale_rows, rng) -> (R, K) Gamma(shape) * scale draws
 GammaRowsFn = Callable[[np.ndarray, np.ndarray, np.random.Generator],
                        np.ndarray]
+# (loads (G, K), scales (G, K), weights (G, K), need (G,), trials, rng)
+#  -> (G * trials,) completion times of a one-shot scheme: per trial, the
+#  earliest finish at which the finished workers' weights reach the need
+OneShotFn = Callable[..., np.ndarray]
 
 
 # ---------------------------------------------------------------------------
@@ -97,7 +104,10 @@ class SamplerBackend:
     ``gamma_rows`` (optional) is the batched order-statistic primitive
     the MDS L-sweep draws through; backends that leave it ``None`` fall
     back to the exact numpy draw (``get_gamma_rows``), so any future
-    backend gets the full scheme surface for free.
+    backend gets the full scheme surface for free.  ``one_shot_times``
+    (optional, same rule) computes the one-shot schemes' per-trial
+    completion times (``fixed``, ``uniform``, ``het_mds``, ``hedged``)
+    in one dispatch; left ``None``, those schemes draw on the host.
 
     ``coupled_mds_sweep`` opts the backend into the common-random-numbers
     L-sweep: candidate Erlangs built as cumulative Gamma *increments*
@@ -112,6 +122,7 @@ class SamplerBackend:
     work_exchange_grid: WEGridFn
     description: str = ""
     gamma_rows: Optional[GammaRowsFn] = None
+    one_shot_times: Optional[OneShotFn] = None
     coupled_mds_sweep: bool = False
     # fused whole-panel dispatch for the work-exchange known/unknown pair:
     # (lam (G, K), N, cfg_known, cfg_unknown, trials, rng,
@@ -1019,6 +1030,20 @@ def gamma_rows_pallas(shape_rows: np.ndarray, scale_rows: np.ndarray,
     return np.array(out)      # own the memory: callers sort in place
 
 
+def one_shot_times_device(loads: np.ndarray, scales: np.ndarray,
+                          weights: np.ndarray, need: np.ndarray, trials: int,
+                          rng: np.random.Generator) -> np.ndarray:
+    """One-shot completion times, ``(G * trials,)`` float32, in one
+    device dispatch (``repro.kernels.we_rounds.one_shot_grid``: the
+    counter-based Threefry + MT-transform Gamma of ``gamma_rows_pallas``,
+    then each trial's weighted cover time).  The numpy ``rng`` only
+    seeds the Threefry key (one draw)."""
+    from repro.kernels.we_rounds import one_shot_grid
+
+    seed = rng.integers(0, 2 ** 32, size=2, dtype=np.uint32)
+    return one_shot_grid(loads, scales, weights, need, trials, seed)
+
+
 # ---------------------------------------------------------------------------
 # fused whole-panel dispatch: the work-exchange pair in one engine
 # ---------------------------------------------------------------------------
@@ -1468,6 +1493,7 @@ register_backend(SamplerBackend(
                 "+ normal-limit binomial, float32); statistically "
                 "equivalent, not bit-identical",
     gamma_rows=gamma_rows_jax,
+    one_shot_times=one_shot_times_device,
     coupled_mds_sweep=True,
     work_exchange_panel=work_exchange_panel_jax),
     available=_jax_available)
@@ -1480,6 +1506,7 @@ register_backend(SamplerBackend(
                 "tiled pass); compiled on TPU, jnp reference / "
                 "interpreted kernel (bit-identical) on CPU",
     gamma_rows=gamma_rows_pallas,
+    one_shot_times=one_shot_times_device,
     coupled_mds_sweep=True,
     work_exchange_panel=work_exchange_panel_pallas),
     available=_jax_available)
@@ -1492,6 +1519,6 @@ __all__ = [
     "grid_sharding", "active_grid_mesh",
     "work_exchange_grid_numpy", "work_exchange_grid_jax",
     "work_exchange_grid_pallas", "gamma_rows_numpy", "gamma_rows_jax",
-    "gamma_rows_pallas", "work_exchange_panel_jax",
+    "gamma_rows_pallas", "one_shot_times_device", "work_exchange_panel_jax",
     "work_exchange_panel_pallas",
 ]

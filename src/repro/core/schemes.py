@@ -41,7 +41,7 @@ from typing import Dict, List, Literal, Optional, Sequence, Tuple, Type
 
 import numpy as np
 
-from repro.tracing import span
+from repro.tracing import count, span
 
 from .assignment import (capped_proportional_assignment,
                          largest_remainder_round, proportional_assignment,
@@ -437,6 +437,16 @@ def _grid_reports(scheme_name: str, specs: Sequence[HetSpec], trials: int,
                 for g in range(len(specs))]
 
 
+def _one_shot_device(name: str, rows: int):
+    """The backend's one-shot primitive (``SamplerBackend.one_shot_times``)
+    for a one-shot scheme's grid of one K, or None for the exact host
+    draw; adds the grid's ``rows`` to the counter of the path taken."""
+    fn = get_backend(name).one_shot_times
+    count("schemes.one_shot_rows_" + ("host" if fn is None else "device"),
+          rows)
+    return fn
+
+
 # ---------------------------------------------------------------------------
 # paper schemes
 # ---------------------------------------------------------------------------
@@ -487,23 +497,32 @@ class _StaticScheme(Scheme):
                 rng: np.random.Generator, keep_trials: bool = False,
                 backend: Optional[str] = None) -> List[MCReport]:
         """One draw for the whole grid: (G * trials, K) Gamma matrix, max
-        over busy workers per row.  Same distribution as looped ``mc``."""
-        validate_backend(backend)
+        over busy workers per row -- on the device where the backend has
+        ``one_shot_times`` (a cover that needs every busy worker), else
+        exact on the host.  Same distribution as looped ``mc``."""
+        name = resolve_backend(backend)
         specs = list(het_specs)
         if not specs or len({h.K for h in specs}) != 1:
             return super().mc_grid(specs, N, trials, rng,
                                    keep_trials=keep_trials, backend=backend)
         T = int(trials)
-        shape = np.repeat(np.stack([self.initial_sizes(h, N)
-                                    for h in specs]), T, axis=0)
-        scale = np.repeat(np.stack([1.0 / h.lambdas for h in specs]),
-                          T, axis=0)
-        t = np.zeros(shape.shape)
-        busy = shape > 0
-        t[busy] = rng.gamma(shape=shape[busy], scale=scale[busy])
-        ts = t.max(axis=1).reshape(len(specs), T)
+        loads = np.stack([self.initial_sizes(h, N) for h in specs])
+        scales = np.stack([1.0 / h.lambdas for h in specs])
+        device = _one_shot_device(name, len(specs) * T)
+        if device is not None:
+            busy = loads > 0
+            ts = device(loads, scales, busy, busy.sum(axis=1), T, rng)
+        else:
+            name = "numpy"
+            shape = np.repeat(loads, T, axis=0)
+            scale = np.repeat(scales, T, axis=0)
+            t = np.zeros(shape.shape)
+            busy = shape > 0
+            t[busy] = rng.gamma(shape=shape[busy], scale=scale[busy])
+            ts = t.max(axis=1)
+        ts = ts.reshape(len(specs), T)
         return [_report(self.name, ts[g], np.ones(T), np.zeros(T),
-                        keep_trials, extra={"backend": "numpy"})
+                        keep_trials, extra={"backend": name})
                 for g in range(len(specs))]
 
     def _scheduler_rates(self, rates: np.ndarray) -> np.ndarray:
@@ -1069,6 +1088,13 @@ class HetMDSScheme(Scheme):
         t = np.full(load_rows.shape, np.inf)
         busy = load_rows > 0
         t[busy] = rng.gamma(shape=load_rows[busy], scale=scale_rows[busy])
+        return HetMDSScheme._cover_rule(t, load_rows, N)
+
+    @staticmethod
+    def _cover_rule(t: np.ndarray, load_rows: np.ndarray,
+                    N: int) -> np.ndarray:
+        """Per row of finish times ``t`` (inf where idle): sort by time and
+        take the time of the first rank whose running load sum reaches N."""
         order = np.argsort(t, axis=1, kind="stable")
         covered = np.cumsum(np.take_along_axis(load_rows, order, axis=1),
                             axis=1) >= N
@@ -1103,22 +1129,30 @@ class HetMDSScheme(Scheme):
     def mc_grid(self, het_specs: Sequence[HetSpec], N: int, trials: int,
                 rng: np.random.Generator, keep_trials: bool = False,
                 backend: Optional[str] = None) -> List[MCReport]:
-        """Cover times for the whole grid in one (G * trials, K) batch."""
-        validate_backend(backend)
+        """Cover times for the whole grid in one (G * trials, K) batch --
+        on the device where the backend has ``one_shot_times``, else
+        exact on the host."""
+        name = resolve_backend(backend)
         specs = list(het_specs)
         if not specs or len({h.K for h in specs}) != 1:
             return super().mc_grid(specs, N, trials, rng,
                                    keep_trials=keep_trials, backend=backend)
         T = int(trials)
         loads = np.stack([self.initial_sizes(h, N) for h in specs])
-        ts = self._cover_times_rows(
-            np.repeat(loads, T, axis=0),
-            np.repeat(np.stack([1.0 / h.lambdas for h in specs]), T, axis=0),
-            N, rng).reshape(len(specs), T)
+        scales = np.stack([1.0 / h.lambdas for h in specs])
+        device = _one_shot_device(name, len(specs) * T)
+        if device is not None:
+            ts = device(loads, scales, loads, np.full(len(specs), N), T,
+                        rng)
+        else:
+            name = "numpy"
+            ts = self._cover_times_rows(np.repeat(loads, T, axis=0),
+                                        np.repeat(scales, T, axis=0), N, rng)
+        ts = ts.reshape(len(specs), T)
         return [_report(self.name, ts[g], np.ones(T),
                         np.full(T, float(loads[g].sum() - N)), keep_trials,
                         extra={"redundancy": self.redundancy,
-                               "backend": "numpy"})
+                               "backend": name})
                 for g in range(len(specs))]
 
 
@@ -1333,6 +1367,20 @@ class HedgedScheme(Scheme):
         n_comm = np.full(trials, float(loads[strag]))
         return t_comp, n_comm, loads, spare, strag, t_k[:, strag], t_spare
 
+    @staticmethod
+    def _race_weights(loads: np.ndarray, spare: Optional[int],
+                      strag: Optional[int]) -> Tuple[np.ndarray, int]:
+        """Cover weights and need that make the earliest covering finish
+        the replica race: every other loaded worker weighs 2 and each
+        replica 1, so the need (their total less 1) is met when all the
+        others and either replica are done.  With no spare every loaded
+        worker must finish."""
+        weights = 2 * (loads > 0)
+        if spare is None:
+            return weights, int(weights.sum())
+        weights[[strag, spare]] = 1
+        return weights, int(weights.sum()) - 1
+
     def simulate(self, het: HetSpec, N: int,
                  rng: np.random.Generator) -> RunStats:
         t_comp, n_comm, loads, spare, strag, t_strag, t_spare = \
@@ -1356,6 +1404,39 @@ class HedgedScheme(Scheme):
                                           "straggler": float(strag)}
         return _report(self.name, t_comp, np.ones(trials), n_comm,
                        keep_trials, extra=extra)
+
+    def mc_grid(self, het_specs: Sequence[HetSpec], N: int, trials: int,
+                rng: np.random.Generator, keep_trials: bool = False,
+                backend: Optional[str] = None) -> List[MCReport]:
+        """The whole grid in one device dispatch where the backend has
+        ``one_shot_times``: each point's spare column carries the
+        straggler's load at the spare's rate (``initial_sizes``), and the
+        pair races (``_race_weights``).  Otherwise (and for grids of
+        mixed K) the exact per-point loop over ``mc``."""
+        name = resolve_backend(backend)
+        specs = list(het_specs)
+        device = (_one_shot_device(name, len(specs) * int(trials))
+                  if specs and len({h.K for h in specs}) == 1 else None)
+        if device is None:
+            return super().mc_grid(specs, N, trials, rng,
+                                   keep_trials=keep_trials, backend=backend)
+        T = int(trials)
+        layouts = [self._layout(h, N) for h in specs]
+        weights, need = zip(*(self._race_weights(*lay) for lay in layouts))
+        ts = device(np.stack([self.initial_sizes(h, N) for h in specs]),
+                    np.stack([1.0 / h.lambdas for h in specs]),
+                    np.stack(weights), np.array(need), T,
+                    rng).reshape(len(specs), T)
+        reports = []
+        for g, (loads, spare, strag) in enumerate(layouts):
+            extra, dup = {"backend": name}, 0.0
+            if spare is not None:
+                extra.update(spare=float(spare), straggler=float(strag))
+                dup = float(loads[strag])
+            reports.append(_report(self.name, ts[g], np.ones(T),
+                                   np.full(T, dup), keep_trials,
+                                   extra=extra))
+        return reports
 
 
 __all__ = [

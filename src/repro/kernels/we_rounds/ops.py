@@ -30,8 +30,8 @@ import numpy as np
 from repro.tracing import count, span
 
 from .kernel import DEFAULT_BLOCK_B, we_rounds_pallas
-from .ref import (gamma_rows_reference, we_rounds_reference,
-                  we_rounds_reference_panel)
+from .ref import (gamma_rows_reference, one_shot_reference,
+                  we_rounds_reference, we_rounds_reference_panel)
 
 ENV_MODE = "REPRO_WE_ROUNDS_MODE"
 MODES = ("auto", "kernel", "interpret", "reference")
@@ -337,4 +337,39 @@ def gamma_rows_grid(shape_rows: np.ndarray, scale_rows: np.ndarray,
     out = _jit_gamma_rows(boost)(jnp.asarray(shape_rows),
                                  jnp.asarray(scale_rows),
                                  jnp.asarray(seed_arr))
+    return np.asarray(out)
+
+
+@functools.lru_cache(maxsize=8)
+def _jit_one_shot(trials: int, boost: bool):
+    import jax
+
+    def one_shot(loads, scales, weights, need, seed):
+        return one_shot_reference(loads, scales, weights, need, seed,
+                                  trials=trials, boost=boost)
+
+    return jax.jit(one_shot)
+
+
+def one_shot_grid(loads: np.ndarray, scales: np.ndarray,
+                  weights: np.ndarray, need: np.ndarray, trials: int,
+                  seed) -> np.ndarray:
+    """Completion times of a one-shot scheme, ``(G * trials,)`` float32,
+    grid-major, in one jitted dispatch (``ref.one_shot_reference``).
+
+    Only the compact ``(G, K)`` loads, scales and cover weights and the
+    ``(G,)`` needs go to the device; the rows are repeated, drawn and
+    reduced there, and one float per trial comes back.  Every one-shot
+    rule is a weighted cover, so one program serves them all: ``seed``
+    (2 x uint32) and every other input are traced, and a grid shape
+    compiles once.  The boost chain compiles in only when some positive
+    load is below 3.
+    """
+    loads = np.asarray(loads, dtype=np.float32)
+    boost = bool(((loads > 0) & (loads < 3)).any())
+    out = _jit_one_shot(int(trials), boost)(
+        loads, np.asarray(scales, dtype=np.float32),
+        np.asarray(weights, dtype=np.float32),
+        np.asarray(need, dtype=np.float32),
+        np.asarray(seed, dtype=np.uint32).reshape(2))
     return np.asarray(out)

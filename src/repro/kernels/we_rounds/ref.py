@@ -428,3 +428,45 @@ def gamma_rows_reference(shape_rows: jnp.ndarray, scale_rows: jnp.ndarray,
     c0_, _ = threefry2x32(k0, k1, c0, c1 + _U32(2))
     return gamma_mt(z, uniform01(b0), uniform01(b1), uniform01(c0_),
                     alpha, scale)
+
+
+# ---------------------------------------------------------------------------
+# one-shot schemes: one draw per worker, each row reduced to its finish time
+# ---------------------------------------------------------------------------
+
+def cover_time(t: jnp.ndarray, weights: jnp.ndarray,
+               need: jnp.ndarray) -> jnp.ndarray:
+    """Per row of ``(R, K)`` finish times, the earliest time at which the
+    finished workers' weights reach the row's ``need`` (``(R,)``): the
+    least ``t_k`` of a weighted worker with ``sum_j w_j [t_j <= t_k] >=
+    need``.  K^2 compare-adds per row and no sort; it picks the same
+    ``t_k`` as sorting by time and taking the first rank whose running
+    weight reaches ``need``.  A weight-0 column never counts and never
+    finishes a cover (its time may be anything, NaN included); a need of
+    0 is met at time 0.  The sums are exact while a row's weights total
+    less than 2^24."""
+    w = jnp.asarray(weights, jnp.float32)
+    covered = jnp.sum(jnp.where(t[:, None, :] <= t[:, :, None],
+                                w[:, None, :], 0.0), axis=2)
+    need = jnp.asarray(need, jnp.float32)[:, None]
+    first = jnp.min(jnp.where((w > 0) & (covered >= need), t, jnp.inf),
+                    axis=1)
+    return jnp.where(need[:, 0] > 0, first, 0.0)
+
+
+def one_shot_reference(loads: jnp.ndarray, scales: jnp.ndarray,
+                       weights: jnp.ndarray, need: jnp.ndarray,
+                       seed: jnp.ndarray, *, trials: int,
+                       boost: bool) -> jnp.ndarray:
+    """Completion times of a one-shot scheme over ``G`` points x
+    ``trials`` rows, grid-major: each point's ``(K,)`` loads, scales
+    (``1 / lambda``) and cover weights, and its ``need``, repeat to its
+    rows here; each worker finishes at a ``gamma_rows_reference`` draw
+    ``Gamma(load) * scale``, and each row reduces to its ``cover_time``."""
+    R = loads.shape[0] * trials
+
+    def rows(x):
+        return jnp.repeat(x, trials, axis=0, total_repeat_length=R)
+
+    t = gamma_rows_reference(rows(loads), rows(scales), seed, boost=boost)
+    return cover_time(t, rows(weights), rows(need))

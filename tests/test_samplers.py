@@ -546,3 +546,109 @@ class TestFusedPanelDispatch:
             a, b = out[key][0], ref[0]
             se = np.hypot(a.t_comp_std, b.t_comp_std) / np.sqrt(trials)
             assert abs(a.t_comp - b.t_comp) < max(6 * se, 2e-3 * b.t_comp)
+
+
+# ---------------------------------------------------------------------------
+# one-shot schemes on the device
+# ---------------------------------------------------------------------------
+
+ONE_SHOT = ("fixed", "uniform", "het_mds", "hedged")
+# case -> (N, specs): the paper's panel point; loads below 3 (the boost
+# chain); a worker too slow for any share (a load-0 column, no boost)
+ONE_SHOT_CASES = {
+    "panel": (1_000_000, lambda: [HetSpec.uniform_random(
+        50, 50.0, 50.0 ** 2 / 6, RNG(50))]),
+    "boost": (12, lambda: [make_het(K=8, mu=1.0, sigma2=1 / 6, seed=s)
+                           for s in (1, 2)]),
+    "zero_load": (3_000, lambda: [HetSpec(np.array([30.0] * 5 + [1e-3]))]),
+}
+
+
+class TestOneShotDevice:
+    """``fixed``, ``uniform``, ``het_mds`` and ``hedged`` grids through the
+    backends' ``one_shot_times``: draws and per-row reduction on the
+    device, against the exact numpy draw."""
+
+    @pytest.mark.parametrize("case", sorted(ONE_SHOT_CASES))
+    @pytest.mark.parametrize("backend", ["jax", "pallas"])
+    @pytest.mark.parametrize("name", ONE_SHOT)
+    def test_grid_matches_numpy_at_6se(self, name, backend, case):
+        n, specs = ONE_SHOT_CASES[case]
+        specs, trials = specs(), 2048
+        scheme = get_scheme(name)
+        dev = scheme.mc_grid(specs, n, trials, RNG(71), backend=backend)
+        ref = scheme.mc_grid(specs, n, trials, RNG(72), backend="numpy")
+        for a, b in zip(dev, ref):
+            se = np.hypot(a.t_comp_std, b.t_comp_std) / np.sqrt(trials)
+            assert abs(a.t_comp - b.t_comp) < 6 * se, (a.t_comp, b.t_comp)
+            assert (a.n_comm, a.iterations) == (b.n_comm, b.iterations)
+            assert a.extra == {**b.extra, "backend": backend}
+
+    @pytest.mark.parametrize("backend", ["jax", "pallas"])
+    def test_hedged_k1_reduces_like_fixed(self, backend):
+        het = HetSpec(np.array([3.0]))
+        [dev] = get_scheme("hedged").mc_grid([het], 1_000, 512, RNG(73),
+                                             backend=backend)
+        ref = get_scheme("fixed").mc(het, 1_000, 512, RNG(74))
+        assert dev.n_comm == 0 and dev.extra == {"backend": backend}
+        se = np.hypot(dev.t_comp_std, ref.t_comp_std) / np.sqrt(512)
+        assert abs(dev.t_comp - ref.t_comp) < 6 * se
+
+    def test_cover_reduction_equals_sort_rule(self):
+        """On one finish-time matrix with ties (integer times) and load-0
+        columns, the sort-free cover picks exactly the time the argsort /
+        cumsum rule picks."""
+        from repro.core.schemes import HetMDSScheme
+        from repro.kernels.we_rounds import cover_time
+        rng = RNG(75)
+        loads = rng.integers(0, 40, size=(4096, 12))
+        loads[:, 3] = 0
+        t = rng.integers(1, 30, size=loads.shape).astype(np.float32)
+        n = int(0.8 * loads.sum(axis=1).min())
+        want = HetMDSScheme._cover_rule(np.where(loads > 0, t, np.inf),
+                                        loads, n)
+        got = cover_time(np.where(loads > 0, t, np.nan), loads,
+                         np.full(len(t), n))
+        np.testing.assert_array_equal(np.asarray(got), want)
+
+    def test_race_weights_make_the_cover_the_hedged_race(self):
+        """On one matrix with ties: the cover under ``_race_weights`` is
+        the latest of the other loaded workers and the earlier replica,
+        exactly; with no spare it is the plain max over loaded workers."""
+        from repro.core.schemes import HedgedScheme
+        from repro.kernels.we_rounds import cover_time
+        rng = RNG(76)
+        R, K = 512, 9
+        t = rng.integers(1, 12, size=(R, K)).astype(np.float32)
+        rows, want = [], []
+        for r in range(R):
+            loads = rng.integers(0, 4, size=K)
+            spare = None if r % 4 == 0 else int(rng.integers(K))
+            strag = None if spare is None else (spare + 1) % K
+            if spare is not None:
+                loads[spare], loads[strag] = 0, 1
+            rows.append(HedgedScheme._race_weights(loads, spare, strag))
+            eff = np.where(loads > 0, t[r], 0.0)
+            if spare is not None:
+                eff[strag] = min(t[r, strag], t[r, spare])
+            want.append(eff.max())
+        weights, need = (np.array(x) for x in zip(*rows))
+        got = cover_time(t, weights, need)
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.array(want, dtype=np.float32))
+
+    @pytest.mark.parametrize("backend", ["numpy", "jax", "pallas"])
+    def test_counters_advance_on_the_path_taken(self, backend):
+        from repro import tracing
+        specs = [make_het(seed=s) for s in (1, 2, 3)]
+        taken = ("schemes.one_shot_rows_host" if backend == "numpy"
+                 else "schemes.one_shot_rows_device")
+        other = ({"schemes.one_shot_rows_host",
+                  "schemes.one_shot_rows_device"} - {taken}).pop()
+        for name in ONE_SHOT:
+            before = tracing.counters()
+            get_scheme(name).mc_grid(specs, 2_000, 64, RNG(77),
+                                     backend=backend)
+            after = tracing.counters()
+            assert after[taken] - before.get(taken, 0) == 3 * 64, name
+            assert after.get(other, 0) == before.get(other, 0), name

@@ -97,6 +97,17 @@ def test_fused_jax_engine_compiles(one_chip, drift):
     eng.lower(*args, N0, THRESHOLD, np.inf, False, MAX_ITER).compile()
 
 
+def test_one_shot_program_compiles(one_chip):
+    """The one-shot schemes' device program at the panel's size (8 points
+    x 4096 trials x K=50): the rows are repeated, drawn and reduced in
+    fused loops, so the cover's K x K compare never lands in memory."""
+    G, T, k = 8, 4096, 50
+    compiled = ops._jit_one_shot(T, False).lower(
+        _on(one_chip, (G, k)), _on(one_chip, (G, k)), _on(one_chip, (G, k)),
+        _on(one_chip, (G,)), _on(one_chip, (2,), jnp.uint32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 24
+
+
 def test_sharded_we_rounds_panel_compiles(four_chips):
     """The fused-panel kernel under the four-chip ``shard_map`` executor:
     one kernel per device on its block of rows."""
