@@ -21,7 +21,12 @@ def largest_remainder_round(shares: np.ndarray, total: int) -> np.ndarray:
         return np.zeros_like(shares, dtype=np.int64)
     if shares.sum() <= 0:
         shares = np.ones_like(shares)
-    scaled = shares * (total / shares.sum())
+    with np.errstate(over="ignore"):
+        ratio = total / shares.sum()
+    if not np.isfinite(ratio):              # subnormal shares: rescale
+        shares = shares / shares.max()
+        ratio = total / shares.sum()
+    scaled = shares * ratio
     floor = np.floor(scaled).astype(np.int64)
     short = total - int(floor.sum())
     if short > 0:
@@ -46,7 +51,14 @@ def largest_remainder_round_batch(shares: np.ndarray,
     if (row_sum <= 0).any():
         shares = np.where((row_sum <= 0)[:, None], 1.0, shares)
         row_sum = shares.sum(axis=1)
-    scaled = shares * (totals / row_sum)[:, None]
+    with np.errstate(over="ignore"):
+        ratio = totals / row_sum
+    tiny = ~np.isfinite(ratio)              # subnormal rows: rescale
+    if tiny.any():
+        shares = np.where(tiny[:, None],
+                          shares / shares.max(axis=1, keepdims=True), shares)
+        ratio = totals / shares.sum(axis=1)
+    scaled = shares * ratio[:, None]
     floor = scaled.astype(np.int64)        # scaled >= 0, so trunc == floor
     short = totals - floor.sum(axis=1)
     order = np.argsort(floor - scaled, axis=1)

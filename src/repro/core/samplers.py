@@ -987,7 +987,8 @@ def work_exchange_grid_pallas(lam: np.ndarray, N: int, cfg: ExchangeConfig,
     with span("repro.we_rounds.rows"):
         lam_rows = np.repeat(_pad_cols(lam, bucket_cols(K)), int(trials),
                              axis=0)                     # (B, Kb), grid-major
-        # power-of-two bucket >= 128 (the kernel's tile height): panel-sized
+        # power-of-two bucket >= 128 (the kernel's tallest tile; every
+        # height ``tile_rows`` picks divides it): panel-sized
         # grids share a handful of compilations per process, and the bucket
         # is always a whole number of tiles
         lam_rows, B = _pad_rows(lam_rows, bucket=128)

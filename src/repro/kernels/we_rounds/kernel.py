@@ -25,7 +25,24 @@ from jax.experimental import pallas as pl
 
 from . import ref
 
-DEFAULT_BLOCK_B = 128
+DEFAULT_BLOCK_B = 128     # the tallest tile, and the rows' padding quantum
+# Bytes of live round state a tile may hold: ``ref.round_body`` keeps
+# about ``LIVE_TILES`` float32 ``(block_b, K)`` arrays live (the state, a
+# round's five variates, the Gamma and Binomial temporaries, the shares),
+# each laid out over whole 128-lane vregs.
+TILE_STATE_BUDGET = 2 * 1024 * 1024
+LIVE_TILES = 20
+
+
+def tile_rows(K: int) -> int:
+    """The kernel's tile height for ``K`` worker columns: the tallest
+    power of two from 8 (one sublane tile) to ``DEFAULT_BLOCK_B`` whose
+    ``LIVE_TILES`` float32 ``(rows, K)`` arrays fit ``TILE_STATE_BUDGET``.
+    A power of two divides every row bucket (``core.samplers._rows_target``
+    pads to 128 or more).  128 at K <= 128; 8 at K = 2,048."""
+    lanes = -(-int(K) // 128) * 128
+    fit = TILE_STATE_BUDGET // (LIVE_TILES * 4 * lanes)
+    return max(8, min(DEFAULT_BLOCK_B, 1 << max(fit, 1).bit_length() - 1))
 
 
 def _we_rounds_kernel(seed_ref, lam_ref, out_ref, *, K: int, block_b: int,
@@ -109,15 +126,16 @@ def we_rounds_pallas(lam_rows: jnp.ndarray, seed: jnp.ndarray,
                      sched_rows: jnp.ndarray = None,
                      known_flags: jnp.ndarray = None, *,
                      n0: float, threshold: float, cap: float, known: bool,
-                     max_iter: int, block_b: int = DEFAULT_BLOCK_B,
+                     max_iter: int, block_b: int = None,
                      interpret: bool = False) -> jnp.ndarray:
     """Run the fused round pipeline; returns ``(B, 3)``:
     ``[:, 0] = t_comp``, ``[:, 1] = iterations``, ``[:, 2] = n_comm``.
 
-    ``B`` must be a multiple of ``block_b`` (callers pad -- see
-    ``ops.we_rounds_grid``); ``seed`` is a ``(1, 2)`` uint32 array shared
-    by every tile.  ``sched_rows`` (optional ``(B, R, K)``) adds the
-    drifting-scenario per-round rate schedule as a third input: each
+    ``B`` must be a multiple of ``block_b`` (default ``tile_rows(K)``;
+    callers pad -- see ``ops.we_rounds_grid``); ``seed`` is a ``(1, 2)``
+    uint32 array shared by every tile.  ``sched_rows`` (optional
+    ``(B, R, K)``) adds the drifting-scenario per-round rate schedule as
+    a third input: each
     program carries its tile's ``(block_b, R, K)`` schedule in VMEM and
     reads the current round's rates with one ``pl.ds`` dynamic slice on
     the trip counter (counters are untouched, so drift runs keep the same
@@ -127,6 +145,7 @@ def we_rounds_pallas(lam_rows: jnp.ndarray, seed: jnp.ndarray,
     own flag (``known`` is then ignored; pass ``known=False``).
     """
     B, K = lam_rows.shape
+    block_b = tile_rows(K) if block_b is None else int(block_b)
     assert B % block_b == 0, f"pad B={B} to a multiple of {block_b}"
     kern_fn = {
         (False, False): _we_rounds_kernel,

@@ -29,7 +29,7 @@ import numpy as np
 
 from repro.tracing import count, span
 
-from .kernel import DEFAULT_BLOCK_B, we_rounds_pallas
+from .kernel import tile_rows, we_rounds_pallas
 from .ref import (gamma_rows_reference, one_shot_reference,
                   we_rounds_reference, we_rounds_reference_panel)
 
@@ -185,7 +185,7 @@ def _pad_rows(rows: Optional[np.ndarray], pad: int) -> Optional[np.ndarray]:
 def we_rounds_grid(lam_rows: np.ndarray, seed, *, n0: float,
                    threshold: float, cap: float, known,
                    max_iter: int, mode: Optional[str] = None,
-                   block_b: int = DEFAULT_BLOCK_B, mesh=None,
+                   block_b: Optional[int] = None, mesh=None,
                    rate_schedule: Optional[np.ndarray] = None,
                    real_rows: Optional[int] = None
                    ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
@@ -193,8 +193,9 @@ def we_rounds_grid(lam_rows: np.ndarray, seed, *, n0: float,
     ``(t_comp, iterations, n_comm)`` float64 numpy arrays.
 
     ``seed`` is a pair of uint32 (any sequence of two ints).  ``B`` is
-    padded to a multiple of ``block_b`` with copies of row 0 (counters are
-    per global row, so padding never alters real rows).
+    padded to a multiple of ``block_b`` (default ``kernel.tile_rows(K)``)
+    with copies of row 0 (counters are per global row, so padding never
+    alters real rows).
 
     ``known`` is a bool (the single-scheme path) or a ``(B,)`` per-row
     flag array -- the fused-panel mixed mode, where known and unknown
@@ -224,7 +225,8 @@ def we_rounds_grid(lam_rows: np.ndarray, seed, *, n0: float,
         if lam_rows.ndim != 2:
             raise ValueError(f"lam_rows must be (B, K); got "
                              f"{lam_rows.shape}")
-        B = lam_rows.shape[0]
+        B, K = lam_rows.shape
+        block_b = tile_rows(K) if block_b is None else int(block_b)
         sched = None
         if rate_schedule is not None:
             sched = np.asarray(rate_schedule, dtype=np.float32)
@@ -291,12 +293,15 @@ def we_rounds_grid(lam_rows: np.ndarray, seed, *, n0: float,
 
 
 def count_row_rounds(it: np.ndarray, real_rows: int, tile: int) -> None:
-    """Add one launch's row-rounds to the ``repro.tracing`` counters.
+    """Add one launch's rows, tiles and row-rounds to the
+    ``repro.tracing`` counters.
 
-    ``it`` is the launch's whole per-row ``iterations`` output, padding
-    included, in tile order; a tile of ``tile`` rows loops until its
-    slowest row is done.  ``we_rounds.row_rounds_useful`` gains the sum
-    of ``it`` over the first ``real_rows`` rows, and
+    ``we_rounds.rows`` gains ``real_rows`` and ``we_rounds.tiles`` the
+    launch's tiles.  ``it`` is the launch's whole per-row ``iterations``
+    output, padding included, in tile order; a tile of ``tile`` rows
+    loops until its slowest row is done.
+    ``we_rounds.row_rounds_useful`` gains the sum of ``it`` over the
+    first ``real_rows`` rows, and
     ``we_rounds.row_rounds_executed`` gains ``tile`` times the sum over
     tiles of the tile's largest ``it``: padding rows are executed, never
     useful.  ``it`` counts the final phase's one round (``ref.py``'s
@@ -305,6 +310,8 @@ def count_row_rounds(it: np.ndarray, real_rows: int, tile: int) -> None:
     ``tile`` is ``block_b`` for the Pallas kernel and the whole batch
     (one ``while_loop``) for the reference.
     """
+    count("we_rounds.rows", int(real_rows))
+    count("we_rounds.tiles", it.size // tile)
     count("we_rounds.row_rounds_useful", int(it[:real_rows].sum()))
     count("we_rounds.row_rounds_executed",
           tile * int(it.reshape(-1, tile).max(axis=1).sum()))
@@ -362,14 +369,19 @@ def one_shot_grid(loads: np.ndarray, scales: np.ndarray,
     reduced there, and one float per trial comes back.  Every one-shot
     rule is a weighted cover, so one program serves them all: ``seed``
     (2 x uint32) and every other input are traced, and a grid shape
-    compiles once.  The boost chain compiles in only when some positive
-    load is below 3.
+    compiles once.  Weights and needs go as int32, so the cover's sums
+    are exact (``ref.cover_time``).  The boost chain compiles in only
+    when some positive load is below 3.  Adds the rows to the
+    ``schemes.cover_rows`` counter.
     """
     loads = np.asarray(loads, dtype=np.float32)
     boost = bool(((loads > 0) & (loads < 3)).any())
-    out = _jit_one_shot(int(trials), boost)(
-        loads, np.asarray(scales, dtype=np.float32),
-        np.asarray(weights, dtype=np.float32),
-        np.asarray(need, dtype=np.float32),
-        np.asarray(seed, dtype=np.uint32).reshape(2))
-    return np.asarray(out)
+    with span("repro.one_shot"):
+        out = _jit_one_shot(int(trials), boost)(
+            loads, np.asarray(scales, dtype=np.float32),
+            np.asarray(weights, dtype=np.int32),
+            np.asarray(need, dtype=np.int32),
+            np.asarray(seed, dtype=np.uint32).reshape(2))
+        out = np.asarray(out)
+    count("schemes.cover_rows", out.size)
+    return out

@@ -108,6 +108,23 @@ def test_one_shot_program_compiles(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 24
 
 
+def test_wide_panel_programs_compile(one_chip):
+    """At K = 2,048 (``cluster_k2048``): the fused-panel kernel at the
+    tile height ``tile_rows`` picks, and the one-shot program with its
+    int32 cover weights at 8 points x 4096 trials."""
+    k, rows = 2048, ops.tile_rows(2048)
+    fn = ops._jit_kernel_panel(4.096e7, 8192.0, 2e4, MAX_ITER, rows, False)
+    hlo = fn.lower(_on(one_chip, (8 * rows, k)), _on(one_chip, (1, 2),
+                                                     jnp.uint32),
+                   _on(one_chip, (8 * rows, 1))).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    G, T = 8, 4096
+    ops._jit_one_shot(T, False).lower(
+        _on(one_chip, (G, k)), _on(one_chip, (G, k)),
+        _on(one_chip, (G, k), jnp.int32), _on(one_chip, (G,), jnp.int32),
+        _on(one_chip, (2,), jnp.uint32)).compile()
+
+
 def test_sharded_we_rounds_panel_compiles(four_chips):
     """The fused-panel kernel under the four-chip ``shard_map`` executor:
     one kernel per device on its block of rows."""
